@@ -10,6 +10,7 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ops import interpret_mode
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.paged_prefill import paged_prefill_attention
 from repro.kernels.ssd_scan import ssd_scan
@@ -18,6 +19,7 @@ from benchmarks.common import emit
 
 
 def main(quick: bool = False):
+    interp = interpret_mode()
     key = jax.random.PRNGKey(0)
     # flash attention (prefill shape, per chip)
     B, H, K, S, d = 1, 8, 2, 1024 if quick else 2048, 128
@@ -25,7 +27,7 @@ def main(quick: bool = False):
     q = jax.random.normal(ks[0], (B, H, S, d), jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, K, S, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (B, K, S, d), jnp.bfloat16)
-    o = flash_attention(q, k, v, causal=True, interpret=True)
+    o = flash_attention(q, k, v, causal=True, interpret=interp)
     r = ref.flash_attention_ref(q, k, v, causal=True)
     err = float(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32)).max())
     flops = 2.0 * B * H * S * S * d * 2 / 2          # causal half
@@ -43,7 +45,7 @@ def main(quick: bool = False):
     k2 = jax.random.normal(ks[1], (B2, K, T, d), jnp.bfloat16)
     v2 = jax.random.normal(ks[2], (B2, K, T, d), jnp.bfloat16)
     lengths = jax.random.randint(ks[3], (B2,), T // 8, T + 1)
-    o2 = decode_attention(q2, k2, v2, lengths, interpret=True)
+    o2 = decode_attention(q2, k2, v2, lengths, interpret=interp)
     r2 = ref.decode_attention_ref(q2, k2, v2, lengths)
     err2 = float(jnp.abs(o2.astype(jnp.float32)
                          - r2.astype(jnp.float32)).max())
@@ -56,12 +58,12 @@ def main(quick: bool = False):
     nb = T // ps
     P = 1 + B2 * nb
     ks = jax.random.split(key, 5)
-    kp = jax.random.normal(ks[0], (P, ps, K, d), jnp.bfloat16)
-    vp = jax.random.normal(ks[1], (P, ps, K, d), jnp.bfloat16)
+    kp = jax.random.normal(ks[0], (P, K, ps, d), jnp.bfloat16)
+    vp = jax.random.normal(ks[1], (P, K, ps, d), jnp.bfloat16)
     perm = np.random.RandomState(0).permutation(P - 1)[:B2 * nb] + 1
     bt = jnp.asarray(perm.reshape(B2, nb), jnp.int32)
     plen = jax.random.randint(ks[2], (B2,), 0, T + 1)
-    o3 = paged_decode_attention(q2, kp, vp, bt, plen, interpret=True)
+    o3 = paged_decode_attention(q2, kp, vp, bt, plen, interpret=interp)
     r3 = ref.paged_decode_attention_ref(q2, kp, vp, bt, plen)
     err3p = float(jnp.abs(o3.astype(jnp.float32)
                           - r3.astype(jnp.float32)).max())
@@ -84,7 +86,7 @@ def main(quick: bool = False):
     offs = jax.random.randint(ks[3], (B2,), 0, T + 1)
     cls = jax.random.randint(ks[4], (B2,), 1, C + 1)
     o4 = paged_prefill_attention(qp, kq, vq, kp, vp, bt, offs, cls,
-                                 interpret=True)
+                                 interpret=interp)
     r4 = ref.paged_prefill_attention_ref(qp, kq, vq, kp, vp, bt, offs, cls)
     err4 = float(jnp.abs(o4.astype(jnp.float32)
                          - r4.astype(jnp.float32)).max())
@@ -104,7 +106,7 @@ def main(quick: bool = False):
     A = -jnp.exp(jax.random.normal(ks[2], (Hh,)) * 0.3)
     B_ = jax.random.normal(ks[3], (b, L, G, N), jnp.bfloat16)
     C_ = jax.random.normal(ks[4], (b, L, G, N), jnp.bfloat16)
-    y, st = ssd_scan(x, dt, A, B_, C_, chunk=64, interpret=True)
+    y, st = ssd_scan(x, dt, A, B_, C_, chunk=64, interpret=interp)
     yr, sr = ref.ssd_scan_ref(x, dt, A, B_, C_)
     err3 = float(jnp.abs(y - yr).max() / (jnp.abs(yr).max() + 1e-9))
     chunk = 64

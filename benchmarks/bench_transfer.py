@@ -21,6 +21,7 @@ from repro.core.perfmodel import SPOT_INSTANCE, model_perf_from_cfg
 from repro.core.weight_transfer import TransferAgent
 from repro.kernels import ref
 from repro.kernels.dequant import fused_dequant
+from repro.kernels.ops import interpret_mode
 from repro.launch.hlo_analysis import HBM_BW
 from repro.transfer.chunkstore import synthetic_manifest
 from repro.transfer.puller import ChunkPull
@@ -72,7 +73,7 @@ def main(quick: bool = False):
     q = jnp.asarray(rng.randint(-127, 128, (R, C)), jnp.int8)
     scale = jnp.asarray(rng.uniform(1e-4, 1e-2, (C,)), jnp.float32)
     base = jnp.asarray(rng.randn(R, C), jnp.float32)
-    o = fused_dequant(q, scale, base, interpret=True)
+    o = fused_dequant(q, scale, base, interpret=interpret_mode())
     r = ref.dequant_ref(q, scale, base)
     err = float(jnp.abs(o - r).max())
     # fused pass: read int8 q + f32 base, write f32 out (scale negligible)

@@ -19,6 +19,7 @@ from benchmarks import (bench_engine, bench_fault_handling, bench_integrity,
                         bench_static_instances, bench_streaming,
                         bench_trace_throughput, bench_transfer,
                         bench_weight_transfer, roofline)
+from repro.launch.compile_cache import setup_compile_cache
 
 BENCHES = [
     ("fig2_motivation", bench_motivation.main),
@@ -52,6 +53,7 @@ def main() -> None:
     args = ap.parse_args()
     assert not (args.full and args.quick), "--full and --quick conflict"
     quick = not args.full
+    setup_compile_cache()
     failures = 0
     for name, fn in BENCHES:
         if args.only and args.only not in name:

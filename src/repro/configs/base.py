@@ -256,6 +256,13 @@ def register(name: str):
     return deco
 
 
+def depth_cut(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """``cfg`` at its published widths with only the first ``n_layers``
+    layers (whole pattern groups) — how a config is cut to fit a chip."""
+    return dataclasses.replace(cfg, n_layers=n_layers,
+                               name=f"{cfg.name}-{n_layers}L")
+
+
 def padded_variant(cfg: ModelConfig, axis: int = 16):
     """Smallest logical head padding making n_heads divisible by the model
     axis while preserving GQA grouping.  Returns cfg unchanged if already

@@ -657,8 +657,9 @@ class HybridRunner:
         self.tracer.end(self._step_span, tokens=tokens)
         self.metrics.append(reg.snapshot())
         # the seeding controller balances on trainer WORK, which streaming
-        # only relocates (overlap credit included back in): its t_seed
-        # sequence is therefore independent of the collection policy
+        # only relocates (overlap credit included back in); the remote wait
+        # it also reads does end earlier by the credit, so t_seed can drift
+        # slightly between collection policies
         self.scheduler.update(StepStats(
             t_train_wait=self._t_train_wait, t_remote_wait=t_remote_wait,
             t_train=max(self._t_train + self._t_overlap, 1e-9),

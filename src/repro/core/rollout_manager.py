@@ -288,15 +288,16 @@ class RolloutManager:
                 return
             version = pull.manifest.version
             if inst.engine is not None and self.store.snapshot is not None:
-                import jax
+                from repro.kernels.ops import interpret_mode
                 base_p = (inst.engine.params
                           if pull.manifest.codec == "delta-int8" else None)
                 try:
+                    # the fused dequant kernel on a TPU; numpy on the CPU
                     params = self.store.chunkstore.assemble(
                         pull.manifest, inst.chunk_cache,
                         like=inst.engine.params, base_params=base_p,
                         use_pallas=(pull.manifest.codec != "none"
-                                    and jax.default_backend() == "tpu"))
+                                    and not interpret_mode()))
                 except MissingChunkError:
                     # the store's history rolled past this manifest while
                     # the pull was in flight — repull the live version

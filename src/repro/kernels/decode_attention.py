@@ -73,7 +73,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit, static_argnames=("window", "cap", "block_k", "interpret"))
 def decode_attention(q, k, v, lengths, *, window: int = 0, cap: float = 0.0,
-                     block_k: int = 128, interpret: bool = True):
+                     block_k: int = 128, interpret: bool):
     """q: [B, H, d]; k/v: [B, K, T, d] slabs (slot t = position t);
     lengths: [B] valid prefix lengths.  Returns [B, H, d]."""
     B, H, d = q.shape

@@ -95,7 +95,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     cap: float = 0.0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q: [B, H, S, d]; k/v: [B, K, S, d] -> [B, H, S, d].
 
     Head-major layout (better MXU tiling than seq-major: the [S, d] tile is

@@ -1,9 +1,8 @@
 """jit'd wrappers: model-layout entry points with a pallas/ref switch.
 
 The model keeps [B, S, H, d] activations; the kernels use head-major
-[B, H, S, d].  ``interpret`` should be True everywhere off-TPU (this repo's
-CPU container); on TPU backends pass interpret=False for the compiled
-Mosaic kernels.
+[B, H, S, d].  Every caller of a Pallas kernel takes ``interpret`` from
+:func:`interpret_mode`, the one place that decides it from the backend.
 """
 
 from __future__ import annotations
@@ -17,8 +16,16 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: True on the CPU (tests
+    and examples), False on a TPU (Mosaic-compiled).  Any other backend has
+    no kernel path and raises, rather than interpreting on an accelerator."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
 
 
 def attention_bshd(q, k, v, *, causal=True, window=0, cap=0.0,
@@ -30,7 +37,7 @@ def attention_bshd(q, k, v, *, causal=True, window=0, cap=0.0,
     if use_pallas:
         o = flash_attention(qt, kt, vt, causal=causal, window=window,
                             cap=cap, block_q=block_q, block_k=block_k,
-                            interpret=not on_tpu())
+                            interpret=interpret_mode())
     else:
         o = ref.flash_attention_ref(qt, kt, vt, causal=causal,
                                     window=window, cap=cap)
@@ -45,7 +52,7 @@ def decode_bshd(q, k_cache, v_cache, lengths, *, window=0, cap=0.0,
     vt = v_cache.swapaxes(1, 2)
     if use_pallas:
         o = decode_attention(qt, kt, vt, lengths, window=window, cap=cap,
-                             block_k=block_k, interpret=not on_tpu())
+                             block_k=block_k, interpret=interpret_mode())
     else:
         o = ref.decode_attention_ref(qt, kt, vt, lengths, window=window,
                                      cap=cap)
@@ -55,5 +62,5 @@ def decode_bshd(q, k_cache, v_cache, lengths, *, window=0, cap=0.0,
 def ssd(x, dt, A, B, C, *, chunk=64, use_pallas=False):
     if use_pallas:
         return ssd_scan(x, dt, A, B, C, chunk=chunk,
-                        interpret=not on_tpu())
+                        interpret=interpret_mode())
     return ref.ssd_scan_ref(x, dt, A, B, C)

@@ -2,12 +2,14 @@
 against block-table-indexed KV page pools (vLLM/RLAX-style PagedAttention,
 FlashDecoding online softmax over the page stream).
 
-The pools are [num_pages, page_size, K, d]; a sequence's KV is scattered
-across pages named by its block table row.  The block tables (and true
-lengths) are *scalar-prefetched* so the per-page DMA source index is known
-before the kernel body runs — the grid iterates pages, and the BlockSpec
-index map dereferences ``block_tables[b, i]`` to stream exactly the pages a
-sequence owns.  Tail pages past a sequence's true length are skipped with
+The pools are [num_pages, K, page_size, d]; a sequence's KV is scattered
+across pages named by its block table row.  One grid step loads one
+(page, KV head) block of [page_size, d]: Mosaic tiles the last two block
+dimensions (sublane x lane), so the layout keeps them whole.  The block
+tables (and true lengths) are *scalar-prefetched* so the per-page DMA
+source index is known before the kernel body runs — the grid iterates
+pages, and the BlockSpec index map dereferences ``block_tables[b, i]`` to
+stream exactly the pages a sequence owns.  Tail pages past a sequence's true length are skipped with
 ``pl.when`` (no FLOPs, accumulators untouched), so compute scales with the
 actual context, not the padded table width.
 
@@ -58,8 +60,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     @pl.when(k_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [G, d]
-        k = k_ref[0, :, 0].astype(jnp.float32)       # [ps, d]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # [ps, d]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [G, ps]
@@ -89,16 +91,15 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 @functools.partial(
     jax.jit, static_argnames=("cap", "scale", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                           cap: float = 0.0, scale=None,
-                           interpret: bool = True):
-    """q: [B, H, d]; k_pages/v_pages: [P, page_size, K, d] shared pools;
+                           cap: float = 0.0, scale=None, interpret: bool):
+    """q: [B, H, d]; k_pages/v_pages: [P, K, page_size, d] shared pools;
     block_tables: [B, nb] page ids (position p of sequence b lives at
     (block_tables[b, p // ps], p % ps); pad rows with the garbage page 0);
     lengths: [B] true context lengths (0 allowed => zero output).
     ``scale`` defaults to d**-0.5; the serving path passes 1.0 because the
     model pre-scales q.  Returns [B, H, d]."""
     B, H, d = q.shape
-    P, ps, K = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
+    P, K, ps = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
     G = H // K
     qg = q.reshape(B, K, G, d)
@@ -115,12 +116,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(B, K, nb),
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, h, ti, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda b, h, ti, bt, ln: (_live_page(bt, ln, b, ti,
-                                                              ps), 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+                                                              ps), h, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda b, h, ti, bt, ln: (_live_page(bt, ln, b, ti,
-                                                              ps), 0, h, 0)),
+                                                              ps), h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d),
                                lambda b, h, ti, bt, ln: (b, h, 0, 0)),

@@ -82,8 +82,8 @@ def _kernel(bt_ref, off_ref, cl_ref, q_ref, kc_ref, vc_ref, kp_ref, vp_ref,
     # ---- phase 1: prefix pages (skip pages at/past the true offset) ---- #
     @pl.when((ti < n_pages) & (ti * page_size < offset))
     def _prefix():
-        k = kp_ref[0, :, 0].astype(jnp.float32)           # [ps, d]
-        v = vp_ref[0, :, 0].astype(jnp.float32)
+        k = kp_ref[0, 0].astype(jnp.float32)              # [ps, d]
+        v = vp_ref[0, 0].astype(jnp.float32)
         s = _scores(q, k)
         kpos = ti * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         # prefix positions precede every chunk query — only the row's true
@@ -114,11 +114,10 @@ def _kernel(bt_ref, off_ref, cl_ref, q_ref, kc_ref, vc_ref, kp_ref, vp_ref,
     jax.jit, static_argnames=("cap", "scale", "interpret"))
 def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables, offsets,
                             chunk_lens, *, cap: float = 0.0,
-                            scale: Optional[float] = None,
-                            interpret: bool = True):
+                            scale: Optional[float] = None, interpret: bool):
     """q: [B, C, H, d] roped queries (scaled by ``scale``, default d**-0.5);
     k/v: [B, C, K, d] the chunk's own roped K/V (NOT yet in the pool);
-    k_pages/v_pages: [P, page_size, K, d] shared pools holding each row's
+    k_pages/v_pages: [P, K, page_size, d] shared pools holding each row's
     prefix; block_tables: [B, nb] page ids (pad with the garbage page 0);
     offsets: [B] true prefix lengths already in the pool (0 allowed);
     chunk_lens: [B] valid tokens in this right-padded chunk.
@@ -129,7 +128,7 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables, offsets,
     Returns [B, C, H, d].
     """
     B, C, H, d = q.shape
-    P, ps, K = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
+    P, K, ps = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
     G = H // K
     if scale is None:
@@ -160,7 +159,7 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables, offsets,
         # prefix length instead of streaming the padded table width.
         last_live = jnp.maximum((off[b] - 1) // ps, 0)
         i = jnp.minimum(jnp.minimum(ti, nb - 1), last_live)
-        return (bt[b, i], 0, h, 0)
+        return (bt[b, i], h, 0, 0)
 
     def _chunk_idx(b, h, qi, ti, bt, off, cl):
         return (b, h, jnp.maximum(ti - nb, 0), 0)
@@ -173,8 +172,8 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables, offsets,
                          lambda b, h, qi, ti, bt, off, cl: (b, h, qi, 0, 0)),
             pl.BlockSpec((1, 1, ckb, d), _chunk_idx),
             pl.BlockSpec((1, 1, ckb, d), _chunk_idx),
-            pl.BlockSpec((1, ps, 1, d), _page_idx),
-            pl.BlockSpec((1, ps, 1, d), _page_idx),
+            pl.BlockSpec((1, 1, ps, d), _page_idx),
+            pl.BlockSpec((1, 1, ps, d), _page_idx),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, qb, G, d),

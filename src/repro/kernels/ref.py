@@ -53,21 +53,26 @@ def decode_attention_ref(q, k, v, lengths, *, window=0, cap=0.0):
     return jnp.einsum("bht,bhtd->bhd", p, vf).astype(q.dtype)
 
 
+def _gather_paged(pages, block_tables):
+    """[P, K, ps, d] pool + [B, nb] tables -> dense [B, nb*ps, K, d] in table
+    order (gathered slot i holds absolute position i)."""
+    g = pages[block_tables]                      # [B, nb, K, ps, d]
+    B, nb, K, ps, d = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * ps, K, d)
+
+
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
                                cap=0.0):
     """Oracle for the paged kernel: gather pages into a dense slab and run
     ``decode_attention_ref``.
 
-    q: [B, H, d]; k_pages/v_pages: [P, ps, K, d]; block_tables: [B, nb];
+    q: [B, H, d]; k_pages/v_pages: [P, K, ps, d]; block_tables: [B, nb];
     lengths: [B].  Gathered slot i holds absolute position i (pages are
     table-ordered).  Rows with length 0 return exactly zero (they have no
     attendable context; the kernel's empty accumulator emits zeros).
     """
-    k = k_pages[block_tables]                    # [B, nb, ps, K, d]
-    B, nb, ps, K, d = k.shape
-    T = nb * ps
-    k = k.reshape(B, T, K, d).transpose(0, 2, 1, 3)
-    v = v_pages[block_tables].reshape(B, T, K, d).transpose(0, 2, 1, 3)
+    k = _gather_paged(k_pages, block_tables).transpose(0, 2, 1, 3)
+    v = _gather_paged(v_pages, block_tables).transpose(0, 2, 1, 3)
     out = decode_attention_ref(q, k, v, lengths, cap=cap)
     return jnp.where((lengths > 0)[:, None, None], out,
                      jnp.zeros_like(out))
@@ -79,7 +84,7 @@ def paged_prefill_attention_ref(q, k, v, k_pages, v_pages, block_tables,
     dense, concat the chunk K/V, mask, softmax.
 
     q: [B, C, H, d] (unscaled unless ``scale`` given); k/v: [B, C, K, d];
-    k_pages/v_pages: [P, ps, K, d]; block_tables: [B, nb]; offsets /
+    k_pages/v_pages: [P, K, ps, d]; block_tables: [B, nb]; offsets /
     chunk_lens: [B].  Query i of row b attends prefix positions < offsets[b]
     plus chunk positions j <= i with j < chunk_lens[b].  Rows with offset 0
     AND chunk_len 0 return exact zeros (matching the kernel's empty
@@ -88,12 +93,12 @@ def paged_prefill_attention_ref(q, k, v, k_pages, v_pages, block_tables,
     B, C, H, d = q.shape
     K = k.shape[2]
     G = H // K
-    nb, ps = block_tables.shape[1], k_pages.shape[1]
+    nb, ps = block_tables.shape[1], k_pages.shape[2]
     T = nb * ps
     if scale is None:
         scale = d ** -0.5
-    k_pre = k_pages[block_tables].reshape(B, T, K, d)
-    v_pre = v_pages[block_tables].reshape(B, T, K, d)
+    k_pre = _gather_paged(k_pages, block_tables)
+    v_pre = _gather_paged(v_pages, block_tables)
     kk = jnp.concatenate([k_pre, k], axis=1).astype(jnp.float32)  # [B,T+C,K,d]
     vv = jnp.concatenate([v_pre, v], axis=1).astype(jnp.float32)
     kk = jnp.repeat(kk, G, axis=2)                                # [B,T+C,H,d]

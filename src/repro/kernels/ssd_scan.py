@@ -70,7 +70,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool):
     """x: [b, L, H, P]; dt: [b, L, H]; A: [H]; B/C: [b, L, G, N].
 
     Returns (y [b, L, H, P] f32, final_state [b, H, P, N] f32).

@@ -168,22 +168,22 @@ def attention_decode(q, k_cache, v_cache, kv_positions, q_positions, *,
 # (ModelRuntime.use_pallas=False; tests/test_ragged_serving.py).
 # --------------------------------------------------------------------------- #
 def gather_pages(pool, block_tables):
-    """pool: [P, ps, K, dh]; block_tables: [B, nb] -> [B, nb*ps, K, dh].
+    """pool: [P, K, ps, dh]; block_tables: [B, nb] -> [B, nb*ps, K, dh].
 
     Gathered slot i holds absolute position i (pages are table-ordered);
     padding table entries point at the garbage page and are masked by the
     caller via position validity.
     """
-    g = pool[block_tables]                       # [B, nb, ps, K, dh]
-    B, nb, ps = g.shape[:3]
-    return g.reshape(B, nb * ps, *g.shape[3:])
+    g = pool[block_tables]                       # [B, nb, K, ps, dh]
+    B, nb, K, ps, dh = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * ps, K, dh)
 
 
 def attention_paged_decode(q, k_pool, v_pool, block_tables, q_positions, *,
                            cap: float) -> jax.Array:
     """One-token decode against paged KV pools.
 
-    q: [B,1,H,dh] roped/scaled.  k_pool/v_pool: [P, ps, K, dh] (roped at
+    q: [B,1,H,dh] roped/scaled.  k_pool/v_pool: [P, K, ps, dh] (roped at
     write).  block_tables: [B, nb].  q_positions: [B] absolute position of
     the query token (== context length already written, minus one... the
     current token's KV must already be written at q_positions).
@@ -228,10 +228,10 @@ def attention_paged_prefill(q, k, v, k_pool, v_pool, block_tables, offsets,
 def paged_write(pool, vals, pages, offs):
     """Scatter token K/V into pool pages.
 
-    pool: [P, ps, K, dh]; vals: [n, K, dh]; pages/offs: [n].  Duplicate
+    pool: [P, K, ps, dh]; vals: [n, K, dh]; pages/offs: [n].  Duplicate
     garbage-page destinations are fine (content is never read unmasked).
     """
-    return pool.at[pages, offs].set(vals.astype(pool.dtype))
+    return pool.at[pages, :, offs].set(vals.astype(pool.dtype))
 
 
 # --------------------------------------------------------------------------- #

@@ -11,7 +11,7 @@ Cache pytree layout mirrors the parameter layout so it scans with the layers:
 
 Layer caches by mixer kind:
   global attn (dense): {"k": [B, T_slab, K, dh], "v": ...}  (slot t = position t)
-  global attn (paged): {"k_pages": [P, page_size, K, dh], "v_pages": ...}
+  global attn (paged): {"k_pages": [P, K, page_size, dh], "v_pages": ...}
                        shared pool; per-request block tables map position
                        p -> (table[p // page_size], p % page_size)
   local attn:  {"k": [B, W, K, dh], "v": ...}               (ring: slot = p % W)
@@ -234,7 +234,8 @@ def init_paged_layer_cache(cfg, mixer: str, batch: int, num_pages: int,
     """Like init_layer_cache but global-attn KV lives in a shared page pool."""
     c: Dict = {}
     if mixer == "global":
-        shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        # [page_size, dh] minor: a (page, KV head) block is one Mosaic tile
+        shape = (num_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
         c["k_pages"] = jnp.zeros(shape, dtype)
         c["v_pages"] = jnp.zeros(shape, dtype)
     elif mixer in ("local", "hybrid"):
@@ -325,7 +326,7 @@ def copy_pool_pages(cache, src, dst):
 def gather_pages(cache, page_ids) -> "Dict[str, np.ndarray]":
     """Host copies of the pool pages at ``page_ids`` from every pool leaf
     (KV-migration export).  Keys are ``jax.tree_util.keystr`` paths; values
-    are ``[n, page_size, K, dh]`` (group-stacked pools: ``[G, n, ...]``)."""
+    are ``[n, K, page_size, dh]`` (group-stacked pools: ``[G, n, ...]``)."""
     ids = np.asarray(page_ids, np.int32)
     out: Dict[str, np.ndarray] = {}
 

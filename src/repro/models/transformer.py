@@ -223,7 +223,7 @@ def _attn_apply(p, h, cfg: ModelConfig, rt: ModelRuntime, mixer: str,
                                             attention_paged_prefill,
                                             paged_write)
         bt = paged["block_tables"]                       # [B, nb]
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[2]
         nb = bt.shape[1]
         # rt.use_pallas routes the serving hot path through the ragged
         # Pallas kernels (interpret mode off-TPU, so CPU CI runs the
@@ -238,7 +238,7 @@ def _attn_apply(p, h, cfg: ModelConfig, rt: ModelRuntime, mixer: str,
             ck = paged_write(cache["k_pages"], k[:, 0], page, pos % ps)
             cv = paged_write(cache["v_pages"], v[:, 0], page, pos % ps)
             if rt.use_pallas:
-                from repro.kernels.ops import on_tpu
+                from repro.kernels.ops import interpret_mode
                 from repro.kernels.paged_attention import \
                     paged_decode_attention
                 # true per-slot lengths: the engine's device-resident
@@ -246,7 +246,7 @@ def _attn_apply(p, h, cfg: ModelConfig, rt: ModelRuntime, mixer: str,
                 # scale with live context, not the padded table width
                 out = paged_decode_attention(
                     q[:, 0], ck, cv, bt, pos + 1, cap=cfg.attn_softcap,
-                    scale=1.0, interpret=not on_tpu())[:, None]
+                    scale=1.0, interpret=interpret_mode())[:, None]
             else:
                 out = attention_paged_decode(q, ck, cv, bt, pos,
                                              cap=cfg.attn_softcap)
@@ -256,13 +256,13 @@ def _attn_apply(p, h, cfg: ModelConfig, rt: ModelRuntime, mixer: str,
             if lens is None:
                 lens = jnp.full((B,), C, jnp.int32)
             if rt.use_pallas:
-                from repro.kernels.ops import on_tpu
+                from repro.kernels.ops import interpret_mode
                 from repro.kernels.paged_prefill import \
                     paged_prefill_attention
                 out = paged_prefill_attention(
                     q, k, v, cache["k_pages"], cache["v_pages"], bt, offs0,
                     lens, cap=cfg.attn_softcap, scale=1.0,
-                    interpret=not on_tpu())
+                    interpret=interpret_mode())
             else:
                 out = attention_paged_prefill(
                     q, k, v, cache["k_pages"], cache["v_pages"], bt, offs0,

@@ -341,7 +341,7 @@ def assemble_kv_state(manifest: Manifest, chunks: Mapping[str, bytes],
     pages = {}
     for leaf, by_page in per_page.items():
         slices = [by_page[j] for j in range(len(by_page))]
-        # page axis: 0 for [ps, K, dh] slices, 1 when a leading G rides
+        # page axis: 0 for [K, ps, dh] slices, 1 when a leading G rides
         pages[leaf] = np.stack(slices, axis=slices[0].ndim - 3)
     return dict(page_size=meta["page_size"], n_pages=meta["n_pages"],
                 requests=meta["requests"], pages=pages,

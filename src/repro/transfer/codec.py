@@ -23,7 +23,7 @@ host fallback.  Quantization convention: leaves are viewed as
 [n, 1] column with one global scale.
 
 The same codecs carry KV-page migrations (``chunkstore.build_kv_manifest``):
-there each manifest leaf is ONE page ``[page_size, K, dh]``, so the int8
+there each manifest leaf is ONE page ``[K, page_size, dh]``, so the int8
 scales are per page x head-dim channel — error <= scale/2 per element,
 bounded by that page's own magnitude (``tests/test_kv_migration.py``
 checks the bound against the ``kernels.ref`` dequant oracle).
@@ -88,14 +88,14 @@ def decode_leaf(payload: bytes, spec, base=None, use_pallas: bool = False):
     if spec.codec == "delta-int8":
         base2 = _rows(np.asarray(base, np.float32))
     if use_pallas:
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels.dequant import fused_dequant
+        from repro.kernels.ops import interpret_mode
         out = np.asarray(fused_dequant(
             jnp.asarray(q2), jnp.asarray(scale),
             jnp.asarray(base2) if base2 is not None else None,
-            interpret=jax.default_backend() != "tpu"))
+            interpret=interpret_mode()))
     else:
         out = q2.astype(np.float32) * scale[None, :]
         if base2 is not None:

@@ -195,15 +195,24 @@ def test_headroom_reservation_across_pool_growth():
     """The up-front horizon reservation grows the pool mid-run without
     perturbing the token stream (tiny pool, H spanning several pages)."""
     kw = dict(max_batch=2, slab_len=8, page_size=4)
-    prompt = tok.encode("1+2=")
-    key = request_key(1, 8)
-    # budget beyond the initial 8-usable-page (32-token) pool
+    # a 30-token prompt nearly fills the initial 8-usable-page (32-token)
+    # pool, so the first H=8 reservation must grow it — whatever the
+    # sampled tokens, as long as the request decodes at all
+    prompt = tok.encode("12345+67890=" * 2 + "12345")
+    assert 28 < len(prompt) <= 32
     max_total = len(prompt) + 32
-    ref = _run(_mk(1, **kw), [(8, prompt, max_total, key)])
+    # first key whose reference stream outlives its prefill token (an EOS
+    # first token would end the request before any decode reservation)
+    for rid in range(8, 40):
+        key = request_key(1, rid)
+        ref = _run(_mk(1, **kw), [(rid, prompt, max_total, key)])
+        if len(ref[rid]) > 1:
+            break
+    assert len(ref[rid]) > 1
     eng = _mk(8, **kw)
     pages0 = eng.alloc.num_pages
-    out = _run(eng, [(8, prompt, max_total, key)])
-    assert _toks(out[8]) == _toks(ref[8])
+    out = _run(eng, [(rid, prompt, max_total, key)])
+    assert _toks(out[rid]) == _toks(ref[rid])
     assert eng.alloc.num_pages > pages0, "pool never grew"
     assert eng.alloc.n_free == eng.alloc.num_pages - 1
 

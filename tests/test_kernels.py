@@ -71,8 +71,8 @@ def test_paged_decode_attention(B, H, K, ps, nb, d, cap, dtype):
     P = 1 + B * nb                             # page 0 = garbage
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
     q = jax.random.normal(ks[0], (B, H, d), dtype)
-    kp = jax.random.normal(ks[1], (P, ps, K, d), dtype)
-    vp = jax.random.normal(ks[2], (P, ps, K, d), dtype)
+    kp = jax.random.normal(ks[1], (P, K, ps, d), dtype)
+    vp = jax.random.normal(ks[2], (P, K, ps, d), dtype)
     perm = np.random.RandomState(3).permutation(P - 1)[:B * nb] + 1
     bt = jnp.asarray(perm.reshape(B, nb), jnp.int32)
     # first rows pin the edge cases, the rest are random ragged lengths
@@ -108,8 +108,8 @@ def test_paged_prefill_attention(B, C, H, K, ps, nb, d, cap, dtype):
     q = jax.random.normal(ks[0], (B, C, H, d), dtype)
     k = jax.random.normal(ks[1], (B, C, K, d), dtype)
     v = jax.random.normal(ks[2], (B, C, K, d), dtype)
-    kp = jax.random.normal(ks[3], (P, ps, K, d), dtype)
-    vp = jax.random.normal(ks[4], (P, ps, K, d), dtype)
+    kp = jax.random.normal(ks[3], (P, K, ps, d), dtype)
+    vp = jax.random.normal(ks[4], (P, K, ps, d), dtype)
     perm = np.random.RandomState(2).permutation(P - 1)[:B * nb] + 1
     bt = jnp.asarray(perm.reshape(B, nb), jnp.int32)
     offs = np.asarray(([0, ps // 2 + 1, ps, nb * ps])[:B], np.int32)
@@ -137,8 +137,8 @@ def test_paged_prefill_matches_dense_model_oracle():
     q = jax.random.normal(ks[0], (B, C, H, d), jnp.float32)
     k = jax.random.normal(ks[1], (B, C, K, d), jnp.float32)
     v = jax.random.normal(ks[2], (B, C, K, d), jnp.float32)
-    kp = jax.random.normal(ks[3], (P, ps, K, d), jnp.float32)
-    vp = jax.random.normal(ks[4], (P, ps, K, d), jnp.float32)
+    kp = jax.random.normal(ks[3], (P, K, ps, d), jnp.float32)
+    vp = jax.random.normal(ks[4], (P, K, ps, d), jnp.float32)
     perm = np.random.RandomState(6).permutation(P - 1)[:B * nb] + 1
     bt = jnp.asarray(perm.reshape(B, nb), jnp.int32)
     offs = jnp.asarray([0, 7, 3 * ps], jnp.int32)
@@ -164,12 +164,12 @@ def test_paged_matches_dense_decode_attention():
     # scatter the dense slab into pages following a block table
     perm = np.random.RandomState(7).permutation(B * nb) + 1
     bt = jnp.asarray(perm.reshape(B, nb), jnp.int32)
-    kp = jnp.zeros((1 + B * nb, ps, K, d), jnp.float32)
+    kp = jnp.zeros((1 + B * nb, K, ps, d), jnp.float32)
     vp = jnp.zeros_like(kp)
-    kt = k.transpose(0, 2, 1, 3).reshape(B, nb, ps, K, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(B, nb, ps, K, d)
-    kp = kp.at[bt.reshape(-1)].set(kt.reshape(B * nb, ps, K, d))
-    vp = vp.at[bt.reshape(-1)].set(vt.reshape(B * nb, ps, K, d))
+    kt = k.reshape(B, K, nb, ps, d).transpose(0, 2, 1, 3, 4)
+    vt = v.reshape(B, K, nb, ps, d).transpose(0, 2, 1, 3, 4)
+    kp = kp.at[bt.reshape(-1)].set(kt.reshape(B * nb, K, ps, d))
+    vp = vp.at[bt.reshape(-1)].set(vt.reshape(B * nb, K, ps, d))
     out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
     want = ref.decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -251,3 +251,17 @@ def test_dequant_kernel(R, C, with_base, block_rows):
     want = ref.dequant_ref(q, scale, base)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", RuntimeError)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, want):
+    """Interpret mode only on the CPU; a backend with no kernel path raises
+    rather than silently interpreting on an accelerator."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is want

@@ -232,7 +232,7 @@ def test_mid_group_partial_migration():
 # --------------------------------------------------------------------------- #
 def test_int8_kv_page_error_bound_vs_ref_oracle():
     rng = np.random.RandomState(0)
-    page = rng.randn(8, 2, 16).astype(np.float32) * 3.0   # [ps, K, dh]
+    page = rng.randn(2, 8, 16).astype(np.float32) * 3.0   # [K, ps, dh]
     payload = codec_mod.encode_leaf(page, "int8")
     spec = LeafSpec("kv:page:0:x", page.shape, "float32", "int8", 0,
                     len(payload))

@@ -387,15 +387,23 @@ def test_engine_spans_cover_step_swap_and_kv_migration():
 
     tr = Tracer(tick)
     kw = dict(max_batch=4, slab_len=64, temperature=1.0, page_size=8,
-              use_pallas=False, tracer=tr)
-    src = InferenceEngine(cfg, params, **kw)
-    dst = InferenceEngine(cfg, params, **kw)
-
+              use_pallas=False)
     prompt = tok.encode("12+34=")
-    src.add_request(0, prompt, request_key(0, 0), len(prompt) + 12,
-                    len(prompt))
-    for _ in range(3):
-        src.step()
+    max_total = len(prompt) + 12
+
+    def run3(engine, key):
+        engine.add_request(0, prompt, key, max_total, len(prompt))
+        for _ in range(3):
+            engine.step()
+        return 0 in engine.exportable_request_ids()
+
+    # the migration needs a request still decoding after 3 steps: take the
+    # first key whose sampled stream has not hit EOS by then
+    key = next(k for k in (request_key(0, i) for i in range(32))
+               if run3(InferenceEngine(cfg, params, **kw), k))
+    src = InferenceEngine(cfg, params, tracer=tr, **kw)
+    dst = InferenceEngine(cfg, params, tracer=tr, **kw)
+    assert run3(src, key)
     src.swap_weights(params, version=7)
     state = src.export_request_state([0])
     src.drop_request(0)

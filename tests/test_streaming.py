@@ -238,9 +238,14 @@ def test_real_streamed_vs_batch_final_params_bit_identical():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree.leaves(hb.opt), jax.tree.leaves(hs.opt)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # the overlap is real and shows up as wall-clock-of-the-event-clock
+    # the overlap is real and shows up as wall-clock-of-the-event-clock:
+    # from the same start, the first step ends earlier by exactly its
+    # tail credit (later steps also see the seeding controller react to
+    # the shorter remote wait, so their ends are not compared)
     assert ms_[-1]["rollout.overlap_s"] > 0.0
-    assert ms_[-1]["step.t_end"] < mb_[-1]["step.t_end"]
+    assert ms_[0]["step.t_start"] == mb_[0]["step.t_start"]
+    assert ms_[0]["step.t_end"] == pytest.approx(
+        mb_[0]["step.t_end"] - ms_[0]["train.t_overlap_s"], abs=1e-9)
     # rewards were scored at row completion, and none were left behind
     assert hs.runner.collector.n_rows_preprocessed == 4 * 3
     assert not hs._reward_cache
