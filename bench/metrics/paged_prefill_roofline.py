@@ -1,0 +1,9 @@
+"""Roofline share of the ragged paged prefill kernel
+(``kernels/paged_prefill.py``), in %: the work of one call at the true
+prefix and chunk lengths and the pool's itemsize, over its mean device
+time."""
+from bench.metrics_common import kernel_roofline
+
+
+def read(record):
+    return kernel_roofline(record, "prefill")
