@@ -137,5 +137,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(bt, lens, qg, k_pages, v_pages)
     return out.reshape(B, H, d)

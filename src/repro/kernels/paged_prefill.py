@@ -190,5 +190,6 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables, offsets,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, C, G, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(bt, offs, cls, qg, kc, vc, k_pages, v_pages)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, C, H, d)

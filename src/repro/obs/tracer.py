@@ -17,6 +17,11 @@ the **null tracer** (module singleton :data:`NULL_TRACER`) makes the
 disabled case a constant-time no-op method call, which is what keeps
 the "recording off" overhead at ~0 (guarded by ``bench_obs``).
 
+``Tracer(time.perf_counter, annotate=True)`` also opens a
+``jax.profiler.TraceAnnotation`` named after each span (attrs stay in the
+ring), so under ``jax.profiler.start_trace`` the real engine's spans land
+on the profiler's host plane, on the device trace's clock.
+
 Span taxonomy (ROADMAP "Telemetry plane" notes):
 
   instance lanes (``inst:N``): ``prefill.chunk``, ``decode.horizon``,
@@ -28,16 +33,24 @@ Span taxonomy (ROADMAP "Telemetry plane" notes):
   trainer lane (``trainer``): ``rl.step``, ``train.microbatch``,
     ``collect.flush`` (streamed collection: tail-flush window whose
     preprocess share overlapped the rollout)
-  engine lanes (real backend, wall clock): ``engine.prefill``,
-    ``engine.decode``, ``engine.swap_weights``, ``engine.kv_export``,
-    ``engine.kv_import``
+  engine lanes (real backend, wall clock): per ``step()``,
+    ``engine.decode`` (attrs ``rows``, ``ctx``, ``pages_used``,
+    ``pages_committed``, ``new_program``) > ``engine.decode.host``,
+    ``engine.decode.wait``, ``engine.decode.unpack``; ``engine.prefill``
+    (attrs ``rows``, ``new_program``) > ``engine.prefill.host``,
+    ``engine.prefill.wait``, ``engine.sample`` > ``engine.sample.wait``.
+    A name ending in ``.wait`` is the host blocked on the device, and
+    nothing else.  Per request, ``engine.queued``
+    (admission to first token; closed ``outcome=served`` or ``dropped``;
+    never annotated).  Between steps ``engine.swap_weights`` (instant),
+    ``engine.kv_export``, ``engine.kv_import`` (attr ``n_pages``)
 """
 
 from __future__ import annotations
 
 import json
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -72,26 +85,40 @@ class Tracer:
     ``clock`` — ``EventLoop.now`` getter for the sim world,
     ``time.perf_counter`` for the real engine.  ``capacity`` bounds the
     ring buffer; ``jsonl_path`` additionally streams every CLOSED span
-    as one JSON line (instants close immediately)."""
+    as one JSON line (instants close immediately).  ``annotate`` opens a
+    profiler annotation per span (see the module docstring); a span opened
+    with ``annotate=False`` gets none, for spans that outlive the host
+    work they would otherwise label."""
 
     enabled = True
 
     def __init__(self, clock: Callable[[], float], *,
                  capacity: int = 65536,
-                 jsonl_path: Optional[str] = None):
+                 jsonl_path: Optional[str] = None,
+                 annotate: bool = False):
         self.clock = clock
         self._spans: deque = deque(maxlen=capacity)
         self._next_id = 0
         self._jsonl = open(jsonl_path, "w") if jsonl_path else None
+        self._annotation = None
+        if annotate:                    # the sim's tracers never import JAX
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        self._open_annotations: Dict[int, object] = {}   # by span_id
 
     # ---------------- recording ---------------- #
     def begin(self, name: str, lane: str, *,
               parent: Optional[Span] = None,
-              t0: Optional[float] = None, **attrs) -> Span:
+              t0: Optional[float] = None, annotate: bool = True,
+              **attrs) -> Span:
         """Open a span.  ``t0`` overrides the clock for retroactive spans
         (the sim emits a fused step's prefill/decode spans when the step
         *fires*, back-dating them to when it was scheduled)."""
         self._next_id += 1
+        if self._annotation is not None and annotate:
+            a = self._annotation(name)
+            a.__enter__()
+            self._open_annotations[self._next_id] = a
         s = Span(name=name, t0=self.clock() if t0 is None else t0,
                  lane=lane, span_id=self._next_id,
                  parent_id=(parent.span_id if parent is not None else None),
@@ -103,6 +130,9 @@ class Tracer:
             **attrs) -> Span:
         if span.t1 is None:             # idempotent on double-close
             span.t1 = self.clock() if t1 is None else t1
+            a = self._open_annotations.pop(span.span_id, None)
+            if a is not None:
+                a.__exit__(None, None, None)
             if attrs:
                 span.attrs.update(attrs)
             self._sink(span)
@@ -112,9 +142,7 @@ class Tracer:
               parent: Optional[Span] = None, **attrs) -> Span:
         """Zero-duration instant (t1 == t0): swaps, grace notices, kills."""
         s = self.begin(name, lane, parent=parent, **attrs)
-        s.t1 = s.t0
-        self._sink(s)
-        return s
+        return self.end(s, t1=s.t0)
 
     @contextmanager
     def span(self, name: str, lane: str, *,
@@ -154,8 +182,10 @@ class _NullTracer(Tracer):
     def __init__(self):
         super().__init__(lambda: 0.0, capacity=1)
         self._dummy = Span("", 0.0, "", 0, t1=0.0)
+        self._null_span = nullcontext(self._dummy)
 
-    def begin(self, name, lane, *, parent=None, t0=None, **attrs):
+    def begin(self, name, lane, *, parent=None, t0=None, annotate=True,
+              **attrs):
         return self._dummy
 
     def end(self, span, *, t1=None, **attrs):
@@ -164,9 +194,8 @@ class _NullTracer(Tracer):
     def event(self, name, lane, *, parent=None, **attrs):
         return self._dummy
 
-    @contextmanager
     def span(self, name, lane, *, parent=None, **attrs):
-        yield self._dummy
+        return self._null_span
 
     def spans(self):
         return []

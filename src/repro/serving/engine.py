@@ -141,7 +141,8 @@ def _get_prefill_fn(cfg: ModelConfig, rt, n: int, C: int, nb: int):
     """Batched chunk prefill: n rows of C tokens against paged prefixes."""
     key = _prefill_family(cfg, n, C, rt.use_pallas) + (nb,)
     if key not in _JIT_CACHE:
-        def fn(params, cache, slot_idx, tokens, mask, offsets, bt):
+        def engine_prefill(params, cache, slot_idx, tokens, mask, offsets,
+                           bt):
             rows = kvc.gather_rows(cache, slot_idx)
             out = forward(params, cfg, rt, tokens=tokens, seq_mask=mask,
                           cache=rows, mode="prefill",
@@ -154,7 +155,7 @@ def _get_prefill_fn(cfg: ModelConfig, rt, n: int, C: int, nb: int):
             logits = logits_from_hidden(params, cfg, hidden_last)  # [n, V]
             return cache, logits
         _JIT_STATS["compiles"] += 1
-        _JIT_CACHE[key] = jax.jit(fn, donate_argnums=(1,))
+        _JIT_CACHE[key] = jax.jit(engine_prefill, donate_argnums=(1,))
     return _JIT_CACHE[key]
 
 
@@ -181,7 +182,8 @@ def _get_decode_fn(cfg: ModelConfig, rt, nb: int, temperature: float,
     if key not in _JIT_CACHE:
         t = temperature if temperature > 0 else 1.0
 
-        def fn(params, cache, tokens, rkeys, active, max_total, bt):
+        def engine_decode(params, cache, tokens, rkeys, active, max_total,
+                          bt):
             def body(carry, _):
                 cache, tokens, active = carry
                 old_pos = cache["pos"]
@@ -211,7 +213,7 @@ def _get_decode_fn(cfg: ModelConfig, rt, nb: int, temperature: float,
             return cache, tokens, active, toks.T, lps.T, em.T
 
         _JIT_STATS["compiles"] += 1
-        _JIT_CACHE[key] = jax.jit(fn, donate_argnums=(1, 2, 4))
+        _JIT_CACHE[key] = jax.jit(engine_decode, donate_argnums=(1, 2, 4))
     return _JIT_CACHE[key]
 
 
@@ -220,7 +222,7 @@ def _get_batch_sample_fn(temperature: float, m: int):
     (was one jit dispatch per GRPO group member)."""
     key = ("sample", temperature, m)
     if key not in _JIT_CACHE:
-        def fn(logits, key_data, pos):
+        def engine_sample(logits, key_data, pos):
             t = temperature if temperature > 0 else 1.0
             nxt = sample_token(logits, key_data, pos, temperature)
             lse = jax.nn.logsumexp(logits / t, axis=-1)
@@ -228,17 +230,17 @@ def _get_batch_sample_fn(temperature: float, m: int):
                 logits / t, nxt[:, None], axis=-1)[:, 0] - lse
             return nxt, lp
         _JIT_STATS["compiles"] += 1
-        _JIT_CACHE[key] = jax.jit(fn)
+        _JIT_CACHE[key] = jax.jit(engine_sample)
     return _JIT_CACHE[key]
 
 
 def _get_copy_fn(cfg: ModelConfig, m: int):
     key = ("copy", cfg.name, cfg.d_model, m)
     if key not in _JIT_CACHE:
-        def fn(cache, src, dst):
+        def engine_copy(cache, src, dst):
             return kvc.copy_pool_pages(cache, src, dst)
         _JIT_STATS["compiles"] += 1
-        _JIT_CACHE[key] = jax.jit(fn, donate_argnums=(0,))
+        _JIT_CACHE[key] = jax.jit(engine_copy, donate_argnums=(0,))
     return _JIT_CACHE[key]
 
 
@@ -263,6 +265,7 @@ class _WaitRow:
     table: List[int]
     members: List[Tuple[int, np.ndarray, int, int, int]]
     # (req_id, key_data, max_total, n_prompt, slot)
+    queued: object                  # ``engine.queued``: admission -> first token
     done: int = 0                   # tokens already prefilled (chunking)
 
 
@@ -353,8 +356,6 @@ class InferenceEngine:
         self.n_decode_dispatches = 0            # fused horizon launches
         self.n_state_uploads = 0                # host->device state syncs
         self.n_bt_uploads = 0                   # host->device block tables
-        self.n_kv_export_pages = 0              # migration: pages shipped out
-        self.n_kv_import_pages = 0              # migration: pages adopted
         self.n_kv_import_tokens = 0             # context resumed w/o prefill
 
     # ------------------------------------------------------------------ #
@@ -521,7 +522,11 @@ class InferenceEngine:
         max_tot = max(m[2] for m in members)
         self._check_admission(L, max_tot, need_slots=len(members))
         table = self._alloc_table(L)
-        row = _WaitRow(token_ids=list(token_ids), table=table, members=[])
+        queued = self.tracer.begin("engine.queued", self.trace_lane,
+                                   annotate=False,
+                                   req=[m[0] for m in members], tokens=L)
+        row = _WaitRow(token_ids=list(token_ids), table=table, members=[],
+                       queued=queued)
         slots = []
         for req_id, key, max_total in members:
             slot = self._reserve_slot(req_id)
@@ -537,16 +542,33 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def step(self) -> List[StepEvent]:
         tr = self.tracer
-        if not tr.enabled:                      # zero-overhead when off
+        if not tr.enabled:                      # no per-step counters when off
             events = self._decode_phase()
             events.extend(self._prefill_phase())
             return events
-        with tr.span("engine.decode", self.trace_lane,
-                     n_active=self.n_active, horizon=self.horizon):
-            events = self._decode_phase()
-        with tr.span("engine.prefill", self.trace_lane,
-                     n_waiting=len(self.waiting)):
-            events.extend(self._prefill_phase())
+        # counters are read before any reservation; ``new_program`` says
+        # whether the phase built a jitted closure (a compile inside a step).
+        # Attrs pass through begin/end only, never through the span handle:
+        # a tracer may be any object with the Tracer's methods
+        lane = self.trace_lane
+        ctx = [s.ctx_len + 1 for s in self.slots if s is not None]
+        compiles = _JIT_STATS["compiles"]
+        span = tr.begin("engine.decode", lane, horizon=self.horizon,
+                        rows=len(ctx), ctx=ctx,
+                        pages_used=self.alloc.num_pages - 1 - self.alloc.n_free,
+                        pages_committed=self._committed_pages())
+        try:
+            events = self._decode_phase(span)
+        finally:
+            tr.end(span, new_program=_JIT_STATS["compiles"] > compiles)
+        compiles = _JIT_STATS["compiles"]
+        rows: List[Tuple[int, int, bool]] = []
+        span = tr.begin("engine.prefill", lane, n_waiting=len(self.waiting))
+        try:
+            events.extend(self._prefill_phase(span, rows))
+        finally:
+            tr.end(span, new_program=_JIT_STATS["compiles"] > compiles,
+                   rows=rows)
         return events
 
     # ---------------- device-resident state ---------------- #
@@ -590,109 +612,120 @@ class InferenceEngine:
         return self._bt_dev
 
     # ---------------- decode ---------------- #
-    def _decode_phase(self) -> List[StepEvent]:
+    def _decode_phase(self, span=None) -> List[StepEvent]:
         if self.n_active == 0:
             return []
-        H = self.horizon
-        # host-side page bookkeeping, ONCE per horizon: reserve the whole
-        # write window (capacity + COW) for every active slot up front
-        copies: List[Tuple[int, int]] = []
-        for st in self.slots:
-            if st is None:
-                continue
-            copies.extend(self._reserve_decode(st.table, st.ctx_len, H))
-        if copies:
-            m = _bucket(len(copies), minimum=1)
-            src = np.full((m,), GARBAGE_PAGE, np.int32)
-            dst = np.full((m,), GARBAGE_PAGE, np.int32)
-            src[:len(copies)] = [c[0] for c in copies]
-            dst[:len(copies)] = [c[1] for c in copies]
-            fn = _get_copy_fn(self.cfg, m)
-            self.cache = fn(self.cache, jnp.asarray(src), jnp.asarray(dst))
-        bt = self._device_block_tables()
-        self._sync_device_state()
-        fn = _get_decode_fn(self.cfg, self.rt, bt.shape[1],
-                            self.temperature, H)
-        (self.cache, self._dev_tokens, self._dev_active,
-         toks, lps, em) = fn(self.params, self.cache, self._dev_tokens,
-                             self._dev_keys, self._dev_active,
-                             self._dev_maxtot, bt)
-        self.n_decode_dispatches += 1
+        tr, lane, H = self.tracer, self.trace_lane, self.horizon
+        with tr.span("engine.decode.host", lane, parent=span):
+            # host-side page bookkeeping, ONCE per horizon: reserve the
+            # whole write window (capacity + COW) for every active slot
+            copies: List[Tuple[int, int]] = []
+            for st in self.slots:
+                if st is None:
+                    continue
+                copies.extend(self._reserve_decode(st.table, st.ctx_len, H))
+            if copies:
+                m = _bucket(len(copies), minimum=1)
+                src = np.full((m,), GARBAGE_PAGE, np.int32)
+                dst = np.full((m,), GARBAGE_PAGE, np.int32)
+                src[:len(copies)] = [c[0] for c in copies]
+                dst[:len(copies)] = [c[1] for c in copies]
+                fn = _get_copy_fn(self.cfg, m)
+                self.cache = fn(self.cache, jnp.asarray(src),
+                                jnp.asarray(dst))
+            bt = self._device_block_tables()
+            self._sync_device_state()
+            fn = _get_decode_fn(self.cfg, self.rt, bt.shape[1],
+                                self.temperature, H)
+            (self.cache, self._dev_tokens, self._dev_active,
+             toks, lps, em) = fn(self.params, self.cache, self._dev_tokens,
+                                 self._dev_keys, self._dev_active,
+                                 self._dev_maxtot, bt)
+            self.n_decode_dispatches += 1
         # ONE device->host sync per horizon: unpack [B, H] matrices into the
         # per-token StepEvent stream the rollout manager consumes
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        em = np.asarray(em)
-        events: List[StepEvent] = []
-        for h in range(H):
-            for i, st in enumerate(self.slots):
-                if st is None or not em[i, h]:
-                    continue
-                t = int(toks[i, h])
-                st.tokens.append(t)
-                st.last_token = t
-                st.ctx_len += 1
-                self.tokens_buf[i] = t
-                done = (t == EOS) or (len(st.tokens) >= st.max_total)
-                events.append(StepEvent(req_id=st.req_id, token=t,
-                                        logprob=float(lps[i, h]),
-                                        finished=done,
-                                        weight_version=self.weight_version))
-                if done:
-                    # mirrors the device transition (active->False, token
-                    # parked at the sentinel), so no state re-upload is
-                    # needed; the freed pages stay masked by the active
-                    # mask until any table changes and the bt rebuilds
-                    self._free_slot(i)
+        with tr.span("engine.decode.wait", lane, parent=span):
+            toks = np.asarray(toks)
+            lps = np.asarray(lps)
+            em = np.asarray(em)
+        with tr.span("engine.decode.unpack", lane, parent=span):
+            events: List[StepEvent] = []
+            for h in range(H):
+                for i, st in enumerate(self.slots):
+                    if st is None or not em[i, h]:
+                        continue
+                    t = int(toks[i, h])
+                    st.tokens.append(t)
+                    st.last_token = t
+                    st.ctx_len += 1
+                    self.tokens_buf[i] = t
+                    done = (t == EOS) or (len(st.tokens) >= st.max_total)
+                    events.append(StepEvent(
+                        req_id=st.req_id, token=t, logprob=float(lps[i, h]),
+                        finished=done, weight_version=self.weight_version))
+                    if done:
+                        # mirrors the device transition (active->False,
+                        # token parked at the sentinel), so no state
+                        # re-upload is needed; the freed pages stay masked
+                        # by the active mask until any table changes and
+                        # the bt rebuilds
+                        self._free_slot(i)
         return events
 
     # ---------------- prefill ---------------- #
-    def _prefill_phase(self) -> List[StepEvent]:
+    def _prefill_phase(self, span=None,
+                       rows: Optional[List[Tuple[int, int, bool]]] = None
+                       ) -> List[StepEvent]:
+        """``rows``, when given, receives each chosen row as ``(offset,
+        take, last)``."""
         if not self.waiting:
             return []
-        budget = max(self.prefill_chunk, 1)
-        chosen: List[Tuple[_WaitRow, int, int]] = []   # (row, start, take)
-        for row in self.waiting:
-            if budget <= 0:
-                break
-            rem = len(row.token_ids) - row.done
-            take = min(rem, budget) if self._chunkable else rem
-            chosen.append((row, row.done, take))
-            budget -= take
-        n_rows = len(chosen)
-        n = _bucket(n_rows, minimum=1)
-        # chunk width buckets to kernel-tile multiples (128): the ragged
-        # prefill kernel always hits a compiled [n, C] grid, and short
-        # chunks of many widths reuse ONE closure (counted below)
-        max_take = max(take for _, _, take in chosen)
-        C = _tile_bucket(max_take)
-        toks = np.zeros((n, C), np.int32)
-        mask = np.zeros((n, C), np.float32)
-        offsets = np.zeros((n,), np.int32)
-        slot_idx = np.full((n,), self.max_batch, np.int32)  # OOB => dropped
-        widths = [len(row.table) for row, _, _ in chosen]
-        needed = max(widths)
-        family = _prefill_family(self.cfg, n, C, self.use_pallas)
-        nb = _padded_width(family, needed)
-        if nb is None:
-            nb = _bucket(needed, minimum=8)
-        else:
-            _JIT_STATS["padded_reuse"] += 1
-        if C > max_take and family + (nb,) in _JIT_CACHE:
-            _JIT_STATS["chunk_pad_reuse"] += 1
-        bt = np.full((n, nb), GARBAGE_PAGE, np.int32)
-        for i, (row, start, take) in enumerate(chosen):
-            toks[i, :take] = row.token_ids[start:start + take]
-            mask[i, :take] = 1.0
-            offsets[i] = start
-            slot_idx[i] = row.members[0][4]     # owner slot's state rows
-            bt[i, :len(row.table)] = row.table
-        fn = _get_prefill_fn(self.cfg, self.rt, n, C, nb)
-        self.cache, logits = fn(self.params, self.cache,
-                                jnp.asarray(slot_idx), jnp.asarray(toks),
-                                jnp.asarray(mask), jnp.asarray(offsets),
-                                jnp.asarray(bt))
-        logits = np.asarray(logits)
+        tr, lane = self.tracer, self.trace_lane
+        with tr.span("engine.prefill.host", lane, parent=span):
+            budget = max(self.prefill_chunk, 1)
+            chosen: List[Tuple[_WaitRow, int, int]] = []  # (row, start, take)
+            for row in self.waiting:
+                if budget <= 0:
+                    break
+                rem = len(row.token_ids) - row.done
+                take = min(rem, budget) if self._chunkable else rem
+                chosen.append((row, row.done, take))
+                budget -= take
+            n_rows = len(chosen)
+            n = _bucket(n_rows, minimum=1)
+            # chunk width buckets to kernel-tile multiples (128): the ragged
+            # prefill kernel always hits a compiled [n, C] grid, and short
+            # chunks of many widths reuse ONE closure (counted below)
+            max_take = max(take for _, _, take in chosen)
+            C = _tile_bucket(max_take)
+            toks = np.zeros((n, C), np.int32)
+            mask = np.zeros((n, C), np.float32)
+            offsets = np.zeros((n,), np.int32)
+            slot_idx = np.full((n,), self.max_batch, np.int32)  # OOB: dropped
+            widths = [len(row.table) for row, _, _ in chosen]
+            needed = max(widths)
+            family = _prefill_family(self.cfg, n, C, self.use_pallas)
+            nb = _padded_width(family, needed)
+            if nb is None:
+                nb = _bucket(needed, minimum=8)
+            else:
+                _JIT_STATS["padded_reuse"] += 1
+            if C > max_take and family + (nb,) in _JIT_CACHE:
+                _JIT_STATS["chunk_pad_reuse"] += 1
+            bt = np.full((n, nb), GARBAGE_PAGE, np.int32)
+            for i, (row, start, take) in enumerate(chosen):
+                toks[i, :take] = row.token_ids[start:start + take]
+                mask[i, :take] = 1.0
+                offsets[i] = start
+                slot_idx[i] = row.members[0][4]     # owner slot's state rows
+                bt[i, :len(row.table)] = row.table
+            fn = _get_prefill_fn(self.cfg, self.rt, n, C, nb)
+            self.cache, logits = fn(self.params, self.cache,
+                                    jnp.asarray(slot_idx), jnp.asarray(toks),
+                                    jnp.asarray(mask), jnp.asarray(offsets),
+                                    jnp.asarray(bt))
+        with tr.span("engine.prefill.wait", lane, parent=span):
+            logits = np.asarray(logits)
 
         events: List[StepEvent] = []
         completed: List[Tuple[int, _WaitRow]] = []
@@ -704,29 +737,34 @@ class InferenceEngine:
             self.waiting.remove(row)
             self.n_prefills += 1
             completed.append((i, row))
+        if rows is not None:
+            rows.extend((start, take, start + take == len(row.token_ids))
+                        for row, start, take in chosen)
         if not completed:
             return events
 
         # ONE batched first-token sampling call over every member of every
         # completed row (was one jit dispatch per GRPO group member)
-        M = sum(len(row.members) for _, row in completed)
-        m = _bucket(M, minimum=1)
-        sel = np.zeros((m,), np.int32)
-        keys = np.zeros((m, 2), np.uint32)
-        pos = np.zeros((m,), np.int32)
-        e = 0
-        for i, row in completed:
-            L = len(row.token_ids)
-            for (_, key_data, _, _, _) in row.members:
-                sel[e] = i
-                keys[e] = key_data
-                pos[e] = L - 1
-                e += 1
-        sfn = _get_batch_sample_fn(self.temperature, m)
-        nxts, first_lps = sfn(jnp.asarray(logits[sel]), jnp.asarray(keys),
-                              jnp.asarray(pos))
-        nxts = np.asarray(nxts)
-        first_lps = np.asarray(first_lps)
+        with tr.span("engine.sample", lane, parent=span) as sample:
+            M = sum(len(row.members) for _, row in completed)
+            m = _bucket(M, minimum=1)
+            sel = np.zeros((m,), np.int32)
+            keys = np.zeros((m, 2), np.uint32)
+            pos = np.zeros((m,), np.int32)
+            e = 0
+            for i, row in completed:
+                L = len(row.token_ids)
+                for (_, key_data, _, _, _) in row.members:
+                    sel[e] = i
+                    keys[e] = key_data
+                    pos[e] = L - 1
+                    e += 1
+            sfn = _get_batch_sample_fn(self.temperature, m)
+            nxts, first_lps = sfn(jnp.asarray(logits[sel]),
+                                  jnp.asarray(keys), jnp.asarray(pos))
+            with tr.span("engine.sample.wait", lane, parent=sample):
+                nxts = np.asarray(nxts)
+                first_lps = np.asarray(first_lps)
 
         pos_fix: List[Tuple[int, int]] = []     # sibling slots need pos = L
         e = 0
@@ -760,6 +798,7 @@ class InferenceEngine:
                                         weight_version=self.weight_version))
                 if done:
                     self._free_slot(slot)
+            tr.end(row.queued, outcome="served")
         # admission mutated the decode state + tables: re-upload next decode
         self._state_dirty = True
         self._bt_dirty = True
@@ -819,7 +858,6 @@ class InferenceEngine:
                                  n_reqs=len(req_ids), n_pages=len(unique))
         pages = (kvc.gather_pages(self.cache, unique) if unique else {})
         self.tracer.end(span)
-        self.n_kv_export_pages += len(unique)
         return dict(page_size=self.page_size, n_pages=len(unique),
                     pages=pages, requests=requests, slot_state=slot_state)
 
@@ -897,7 +935,6 @@ class InferenceEngine:
                     self.cache, state["slot_state"][rid], slot)
             slots.append(slot)
             self.n_kv_import_tokens += r["ctx_len"]
-        self.n_kv_import_pages += len(used)
         idx = jnp.asarray(slots, jnp.int32)
         val = jnp.asarray([r["ctx_len"] for r in reqs], jnp.int32)
         self.cache["pos"] = self.cache["pos"].at[idx].set(val)
@@ -926,6 +963,7 @@ class InferenceEngine:
                     if not row.members:
                         self.alloc.free_table(row.table)
                         self.waiting.remove(row)
+                        self.tracer.end(row.queued, outcome="dropped")
                     return toks
         return None
 

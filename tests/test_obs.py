@@ -79,6 +79,37 @@ def test_null_tracer_is_inert():
     assert not NULL_TRACER.enabled
 
 
+def test_tracer_annotate_writes_span_names_to_the_profiler(tmp_path):
+    """``annotate=True``: each span opens a profiler annotation of its plain
+    name (attrs stay in the ring); ``annotate=False`` spans stay out."""
+    import glob
+    import time
+
+    import jax
+
+    tr = Tracer(time.perf_counter, annotate=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("obs.step", "engine", rows=3) as step:
+            tr.end(tr.begin("obs.step.wait", "engine", parent=step))
+            tr.end(tr.begin("obs.queued", "engine", annotate=False))
+    finally:
+        jax.profiler.stop_trace()
+    pd = jax.profiler.ProfileData.from_file(
+        glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[0])
+    host = {e.name for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events}
+    assert {"obs.step", "obs.step.wait"} <= host
+    assert "obs.queued" not in host
+    spans = tr.spans()
+    assert [s.name for s in spans] == ["obs.step", "obs.step.wait",
+                                      "obs.queued"]
+    assert spans[0].attrs == {"rows": 3} and spans[1].parent_id == step.span_id
+    assert all(s.closed for s in spans)
+
+
 # --------------------------------------------------------------------------- #
 # metrics registry unit
 # --------------------------------------------------------------------------- #
@@ -420,3 +451,86 @@ def test_engine_spans_cover_step_swap_and_kv_migration():
     assert set(tr.lanes()) == {"engine"}
     for s in spans:
         assert s.t1 is not None and s.t1 >= s.t0   # well-formed, closed
+
+
+_PHASE_PARENT = {"engine.decode.host": "engine.decode",
+                 "engine.decode.wait": "engine.decode",
+                 "engine.decode.unpack": "engine.decode",
+                 "engine.prefill.host": "engine.prefill",
+                 "engine.prefill.wait": "engine.prefill",
+                 "engine.sample": "engine.prefill",
+                 "engine.sample.wait": "engine.sample"}
+
+
+@pytest.mark.parametrize("horizon", [1, 2])
+def test_engine_phase_spans_step_counters_and_queue_wait(horizon):
+    """Inside step(): every phase child hangs off its phase and lies inside
+    it, the step counters ride on the phases, and each admitted row gets one
+    ``engine.queued`` span that closes served or dropped.  The same drive
+    under NULL_TRACER records nothing and emits the same tokens."""
+    import jax
+
+    from repro.data import tokenizer as tok
+    from repro.models import init_params
+    from repro.rl.sampler import request_key
+    from repro.serving.engine import InferenceEngine
+
+    cfg = get_config("qwen2-7b").reduced(n_heads=2, n_kv_heads=1, d_model=32,
+                                         head_dim=16, d_ff=64,
+                                         vocab_size=tok.VOCAB_SIZE)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompt = tok.encode("12+34=" * 3)
+    L = len(prompt)                      # 19 tokens: chunks of 8, 8 and 3
+    clock = [0.0]
+
+    def tick():
+        clock[0] += 0.25
+        return clock[0]
+
+    def drive(tracer):
+        eng = InferenceEngine(cfg, params, max_batch=4, slab_len=64,
+                              temperature=1.0, page_size=8, prefill_chunk=8,
+                              horizon=horizon, use_pallas=False, tracer=tracer)
+        eng.add_group([(0, request_key(0, 0), L + 6),
+                       (1, request_key(0, 1), L + 6)], prompt, L)
+        eng.add_request(2, prompt[:5], request_key(0, 2), 11, 5)
+        eng.add_request(3, prompt, request_key(0, 3), L + 6, L)
+        out = [(e.req_id, e.token) for e in eng.step()]
+        eng.drop_request(3)              # still waiting: nothing prefilled
+        for _ in range(9):
+            out += [(e.req_id, e.token) for e in eng.step()]
+        return out
+
+    tr = Tracer(tick)
+    events = drive(tr)
+    spans = tr.spans()
+    by_id = {s.span_id: s for s in spans}
+    assert {s.name for s in spans} == (set(_PHASE_PARENT) | {
+        "engine.decode", "engine.prefill", "engine.queued"})
+    for s in spans:
+        assert s.closed
+        if s.name in _PHASE_PARENT:      # .wait spans among them, and only
+            p = by_id[s.parent_id]
+            assert p.name == _PHASE_PARENT[s.name]
+            assert p.t0 <= s.t0 <= s.t1 <= p.t1
+    dec = [s.attrs for s in spans if s.name == "engine.decode"]
+    pre = [s.attrs for s in spans if s.name == "engine.prefill"]
+    assert len(dec) == len(pre) == 10
+    for a in dec:
+        assert a["rows"] == len(a["ctx"]) and a["pages_used"] >= 0
+        assert isinstance(a["new_program"], bool)
+    # three members at L + 6 tokens, request 2 at 11: pages of 8
+    assert dec[0]["rows"] == 0
+    assert dec[0]["pages_committed"] == 3 * -(-(L + 6) // 8) + 2
+    for a in pre:
+        assert isinstance(a["new_program"], bool)
+    assert pre[0]["rows"] == [(0, 8, False)]
+    assert pre[2]["rows"] == [(16, L - 16, True), (0, 5, True)]
+    assert dec[3]["rows"] == 3 and dec[3]["ctx"] == [L + 1, L + 1, 6]
+    queued = [s for s in spans if s.name == "engine.queued"]
+    assert [(s.attrs["req"], s.attrs["tokens"], s.attrs["outcome"])
+            for s in queued] == [([0, 1], L, "served"), ([2], 5, "served"),
+                                 ([3], L, "dropped")]
+    prefill3 = [s for s in spans if s.name == "engine.prefill"][2]
+    assert all(prefill3.t0 < s.t1 < prefill3.t1 for s in queued[:2])
+    assert drive(NULL_TRACER) == events and NULL_TRACER.spans() == []
