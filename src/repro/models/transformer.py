@@ -243,9 +243,13 @@ def _attn_apply(p, h, cfg: ModelConfig, rt: ModelRuntime, mixer: str,
                     paged_decode_attention
                 # true per-slot lengths: the engine's device-resident
                 # ``pos`` buffer (SlotState.ctx_len mirror) — HBM reads
-                # scale with live context, not the padded table width
+                # scale with live context, not the padded table width;
+                # rows not decoding keep a frozen ``pos`` and read nothing
+                lengths = pos + 1
+                if "active" in paged:
+                    lengths = jnp.where(paged["active"], lengths, 0)
                 out = paged_decode_attention(
-                    q[:, 0], ck, cv, bt, pos + 1, cap=cfg.attn_softcap,
+                    q[:, 0], ck, cv, bt, lengths, cap=cfg.attn_softcap,
                     scale=1.0, interpret=interpret_mode())[:, None]
             else:
                 out = attention_paged_decode(q, ck, cv, bt, pos,
@@ -383,7 +387,9 @@ def forward(params, cfg: ModelConfig, rt: ModelRuntime, *, tokens=None,
     ``paged`` routes global-attn KV through shared page pools instead of
     per-slot slabs: {"block_tables": [B, nb] int32} plus, for prefill
     chunks, {"q_offsets": [B] int32} — the number of tokens each row already
-    has in the pool (the chunk attends to that prefix and is written after).
+    has in the pool (the chunk attends to that prefix and is written after),
+    and for decode optionally {"active": [B] bool}: the Pallas kernel reads
+    no context for inactive rows, whose outputs the caller discards.
     """
     assert mode in ("train", "prefill", "decode")
     if mode == "decode":

@@ -191,7 +191,8 @@ def _get_decode_fn(cfg: ModelConfig, rt, nb: int, temperature: float,
                                     jnp.int32(GARBAGE_PAGE))
                 out = forward(params, cfg, rt, tokens=tokens,
                               cache=cache, mode="decode",
-                              paged={"block_tables": bt_step})
+                              paged={"block_tables": bt_step,
+                                     "active": active})
                 logits = logits_from_hidden(params, cfg, out["hidden"][:, 0])
                 nxt = sample_token(logits, rkeys, old_pos, temperature)
                 lse = jax.nn.logsumexp(logits / t, axis=-1)

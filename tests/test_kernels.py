@@ -59,28 +59,44 @@ def test_decode_attention(B, H, K, T, d, window, cap, dtype):
                                atol=TOL[dtype], rtol=TOL[dtype])
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,K,ps,nb,d,cap", [
-    (4, 4, 2, 16, 8, 64, 0.0),               # GQA
-    (2, 8, 8, 32, 4, 64, 0.0),               # MHA
-    (3, 4, 1, 8, 16, 128, 30.0),             # MQA + softcap
-])
-def test_paged_decode_attention(B, H, K, ps, nb, d, cap, dtype):
-    """Ragged paged kernel vs the gather-then-dense oracle, including
-    length 0, lengths on a page boundary, and lengths spanning pages."""
+def _paged_case(B, H, K, ps, nb, d, dtype, seed=5):
+    """Pools, block tables and lengths for the paged decode tests.  The
+    first rows pin the edge cases (length 0, a page boundary, one past
+    it, the whole table, mid-page in the last block), the rest are random
+    ragged lengths; batches wider than 8 keep one row in eight live, as
+    the engine's slots do."""
     P = 1 + B * nb                             # page 0 = garbage
-    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, H, d), dtype)
     kp = jax.random.normal(ks[1], (P, K, ps, d), dtype)
     vp = jax.random.normal(ks[2], (P, K, ps, d), dtype)
     perm = np.random.RandomState(3).permutation(P - 1)[:B * nb] + 1
     bt = jnp.asarray(perm.reshape(B, nb), jnp.int32)
-    # first rows pin the edge cases, the rest are random ragged lengths
-    edge = [0, ps, ps + 1, nb * ps]
+    edge = [0, ps, ps + 1, nb * ps, nb * ps - ps // 2 - 1]
     lens = np.asarray(
         (edge + list(np.random.RandomState(4).randint(1, nb * ps + 1,
                                                       size=B)))[:B],
         np.int32)
+    if B > 8:
+        lens[np.arange(B) % 8 != 3] = 0
+    return q, kp, vp, bt, lens
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,H,K,ps,nb,d,cap", [
+    (4, 4, 2, 16, 8, 64, 0.0),               # GQA
+    (2, 8, 8, 32, 4, 64, 0.0),               # MHA
+    (3, 4, 1, 8, 16, 128, 30.0),             # MQA + softcap
+    # blocks are 256 tokens (16 pages of 16) where the table allows:
+    (5, 14, 2, 16, 20, 128, 0.0),            # G 7 (Qwen2); 20 = 16 + 4 pages
+    (5, 8, 2, 16, 5, 128, 0.0),              # G 4 (Qwen3); table < a block
+    (32, 8, 2, 16, 20, 64, 30.0),            # 4 of 32 rows live + softcap
+])
+def test_paged_decode_attention(B, H, K, ps, nb, d, cap, dtype):
+    """Ragged paged kernel vs the gather-then-dense oracle, including
+    length 0, lengths on a page boundary, lengths spanning pages and
+    blocks, and mostly idle batches."""
+    q, kp, vp, bt, lens = _paged_case(B, H, K, ps, nb, d, dtype)
     lengths = jnp.asarray(lens)
     out = paged_decode_attention(q, kp, vp, bt, lengths, cap=cap,
                                  interpret=True)
@@ -91,6 +107,33 @@ def test_paged_decode_attention(B, H, K, ps, nb, d, cap, dtype):
     assert err <= tol, err
     if lens[0] == 0:
         assert float(jnp.abs(out[0]).max()) == 0.0
+
+
+@pytest.mark.parametrize("B,H,K,ps,nb,d", [
+    (5, 14, 2, 16, 20, 128),                 # G 7, two blocks a row
+    (16, 8, 2, 16, 5, 64),                   # G 4, rows of length 0
+])
+def test_paged_decode_reads_only_live_positions(B, H, K, ps, nb, d):
+    """Every pool slot outside a row's live positions (pages past its
+    length, the tail of its last page, all pages of length-0 rows and the
+    garbage page) holds NaN: the output still equals the oracle on the
+    clean pools and is finite."""
+    q, kp, vp, bt, lens = _paged_case(B, H, K, ps, nb, d, jnp.float32)
+    lengths = jnp.asarray(lens)
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    live = np.zeros((kp.shape[0], ps), bool)
+    table = np.asarray(bt)
+    for b, n in enumerate(lens):
+        for p in range(n):
+            live[table[b, p // ps], p % ps] = True
+    dead = jnp.asarray(~live)[:, None, :, None]
+    out = paged_decode_attention(q, jnp.where(dead, jnp.nan, kp),
+                                 jnp.where(dead, jnp.nan, vp), bt, lengths,
+                                 interpret=True)
+    assert bool(jnp.isfinite(out).all())
+    err = float(jnp.abs(out - want).max())
+    assert err <= TOL[jnp.float32], err
+    assert float(jnp.abs(out[lens == 0]).max()) == 0.0
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -83,6 +83,27 @@ def test_paged_decode_compiles_for_v5e(one_chip, dtype):
     _assert_kernel(fn.lower(*args).compile())
 
 
+@pytest.mark.parametrize("kv_heads,group,nb,n_pages", [
+    (4, 7, 512, 9079),          # Qwen2-7B pool and table of the longtail cell
+    (8, 4, 1024, 4116),         # Qwen3-8B pool and table of the longprompt cell
+], ids=["qwen2-7b", "qwen3-8b"])
+def test_paged_decode_compiles_at_cell_shapes(one_chip, kv_heads, group, nb,
+                                              n_pages):
+    """The engine's 64 slots on the benchmark cells' f32 pools and widest
+    block tables: the kernel's page buffers must fit scoped VMEM and its
+    block-table prefetch SMEM."""
+    b = 64
+    pool = _spec((n_pages, kv_heads, PAGE_SIZE, D), ENGINE_POOL_DTYPE,
+                 one_chip)
+    args = (_spec((b, kv_heads * group, D), ENGINE_POOL_DTYPE, one_chip),
+            pool, pool,
+            _spec((b, nb), jnp.int32, one_chip),
+            _spec((b,), jnp.int32, one_chip))
+    fn = jax.jit(lambda *a: paged_decode_attention(*a, scale=1.0,
+                                                   interpret=False))
+    _assert_kernel(fn.lower(*args).compile())
+
+
 @pytest.mark.parametrize("dtype", [ENGINE_POOL_DTYPE, jnp.bfloat16],
                          ids=["pool_dtype", "bf16"])
 def test_paged_prefill_compiles_for_v5e(one_chip, dtype):
