@@ -1,8 +1,9 @@
 """The comparison that decides ``correct`` for served tokens.
 
 The sampled requests' contexts and served tokens are scored by the plain
-float32 reference (``bench/reference``), which regenerates the weights
-from the seed itself.  For each served token, the gap between the
+float32 reference that the configuration names
+(``bench/reference/<reference>.py``, its ``score``), on weights
+regenerated from the seed.  For each served token, the gap between the
 log-probability the engine emitted with it and the reference's
 log-probability of that token at that position is taken; the widest gap
 over all of them is the number compared.  Tokens are sampled at the
@@ -24,8 +25,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import weights
-from bench.reference import qwen_dense
+from bench import spec, weights
 
 
 def _verdict(gaps: List[np.ndarray], limits: dict) -> Dict:
@@ -46,16 +46,17 @@ def _verdict(gaps: List[np.ndarray], limits: dict) -> Dict:
 
 
 def compare_served(c: dict, seed: int, sample: List[dict], limits: dict, *,
-                   control: bool = False) -> Dict:
-    w = weights.make(c, seed)
+                   control: bool = False, root=spec.ROOT) -> Dict:
+    ref_mod = spec.reference(c, root)
+    w = weights.make(c, seed, root)
     prog, ctrl = [], []
     for s in sample:
         toks, k = s["tokens"], s["n_before"]
-        lp_ref, _ = qwen_dense.score(w, c, toks)
+        lp_ref, _ = ref_mod.score(w, c, toks)
         ref = lp_ref[k - 1: len(toks) - 1]
         prog.append(np.asarray(s["lps"], np.float64) - ref)
         if control:
-            lp_c, _ = qwen_dense.score(w, c, toks, precision="fp8")
+            lp_c, _ = ref_mod.score(w, c, toks, precision="fp8")
             ctrl.append(np.asarray(lp_c[k - 1: len(toks) - 1], np.float64) - ref)
     out = _verdict(prog, limits)
     if control:

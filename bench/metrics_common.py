@@ -26,7 +26,8 @@ def idle_share(record):
 
 
 def _work(record, kind):
-    """Mean (flops, bytes) of one kernel call (one layer of one step)."""
+    """Mean (flops, bytes) of one kernel call (one full-attention layer of
+    one step)."""
     c = record["config"]
     H, K = c["num_attention_heads"], c["num_key_value_heads"]
     dh = c.get("head_dim") or c["hidden_size"] // H

@@ -7,9 +7,10 @@ output head.  One sequence at a time, one layer at a time, with no cache,
 no kernel and no batching; attention in blocks of queries so that long
 sequences fit.  Every matrix product runs at ``Precision.HIGHEST``.
 
-It imports nothing of the program.  Weights are read in the layout the
-benchmark makes them in (``bench/weights.py``); RMSNorm weights there are
-stored as ``scale`` with weight ``1 + scale``.
+It imports nothing of the program.  ``shapes`` states the parameter
+layout, which ``bench/weights.py`` draws from the seed and holds to the
+program's; RMSNorm weights there are stored as ``scale`` with weight
+``1 + scale``.
 
 ``precision="fp8"`` is the control: the same computation with both
 operands of every matrix product rounded to float8 e4m3 (one scale per
@@ -30,6 +31,41 @@ Q_BLOCK = 512
 LOGIT_BLOCK = 1024
 SHAPE_BLOCK = 2048      # sequences pad to a multiple: few shapes, rarely a new one
 F8_MAX = 448.0          # largest finite float8_e4m3fn
+
+
+def shapes(c: dict) -> dict:
+    """Leaf path -> (shape, kind, fan-in) of the parameter tree for config
+    ``c``, in the program's layout: the embedding, an untied head when the
+    configuration says so, and one scanned group whose leaves carry the
+    layer index first.  Kinds as ``bench/weights.py`` draws them: "w" a
+    weight (fan-in given), "norm", "bias"."""
+    L, D, F, V = (c["num_hidden_layers"], c["hidden_size"],
+                  c["intermediate_size"], c["vocab_size"])
+    H, K = c["num_attention_heads"], c["num_key_value_heads"]
+    dh = c.get("head_dim") or D // H
+    leaves = {
+        "embed": ((V, D), "w", D),
+        "final_norm/scale": ((D,), "norm", 0),
+        "groups/sub0/ln1/scale": ((L, D), "norm", 0),
+        "groups/sub0/ln2/scale": ((L, D), "norm", 0),
+        "groups/sub0/attn/wq": ((L, D, H, dh), "w", D),
+        "groups/sub0/attn/wk": ((L, D, K, dh), "w", D),
+        "groups/sub0/attn/wv": ((L, D, K, dh), "w", D),
+        "groups/sub0/attn/wo": ((L, H, dh, D), "w", H * dh),
+        "groups/sub0/mlp/wi": ((L, D, F), "w", D),
+        "groups/sub0/mlp/wg": ((L, D, F), "w", D),
+        "groups/sub0/mlp/wo": ((L, F, D), "w", F),
+    }
+    if not c["tie_word_embeddings"]:
+        leaves["lm_head"] = ((D, V), "w", D)
+    if c.get("qkv_bias"):
+        leaves["groups/sub0/attn/bq"] = ((L, H, dh), "bias", 0)
+        leaves["groups/sub0/attn/bk"] = ((L, K, dh), "bias", 0)
+        leaves["groups/sub0/attn/bv"] = ((L, K, dh), "bias", 0)
+    if c.get("qk_norm"):
+        leaves["groups/sub0/attn/q_norm"] = ((L, dh), "norm", 0)
+        leaves["groups/sub0/attn/k_norm"] = ((L, dh), "norm", 0)
+    return leaves
 
 
 def _fp8(x):

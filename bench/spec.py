@@ -4,9 +4,11 @@ A cell (an entry of ``workloads``) names its configuration and its
 traffic mix.  The configuration's file is the ``file`` of its entry under
 ``configs``; the mix is ``bench/traffic/<traffic>.json``, whose
 ``window`` names the timed entry ``bench/windows/<window>.py``; the
-limits of the correctness check are ``bench/limits/<cell>.json``; each
-per-layer metric is read by ``bench/metrics/<metric>.py``.  Adding any of
-them takes new files and new entries only.
+configuration's ``reference`` names its plain reference and parameter
+layout, ``bench/reference/<reference>.py``; the limits of the correctness
+check are ``bench/limits/<cell>.json``; each per-layer metric is read by
+``bench/metrics/<metric>.py``.  Adding any of them takes new files and new
+entries only.
 """
 
 from __future__ import annotations
@@ -50,6 +52,27 @@ def load_module(path: Path):
     return mod
 
 
+def reference_path(c: dict, root: Path = ROOT) -> Path:
+    """``bench/reference/<c["reference"]>.py``, the plain reference that
+    configuration ``c`` names; an error where it names none or a missing
+    file."""
+    name = c.get("reference")
+    if not isinstance(name, str) or not re.fullmatch(r"\w[\w.-]*", name):
+        raise SpecError(f"{c.get('name')}: the configuration names no "
+                        f"reference module (key 'reference')")
+    path = root / "bench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"{c.get('name')}: missing reference {path}")
+    return path
+
+
+def reference(c: dict, root: Path = ROOT):
+    """The reference module of configuration ``c``: its ``shapes(c)`` gives
+    the parameter layout, its ``score(weights, c, tokens, precision=...)``
+    the plain forward."""
+    return load_module(reference_path(c, root))
+
+
 def cell(bm: dict, name: str, root: Path = ROOT) -> dict:
     """Everything one cell needs, resolved from its name."""
     wl = {w["name"]: w for w in bm["workloads"]}
@@ -64,8 +87,10 @@ def cell(bm: dict, name: str, root: Path = ROOT) -> dict:
     mix = _json(bench / "traffic" / f"{w['traffic']}.json")
     return {
         "name": name,
+        "root": root,
         "chips": int(w["chips"]),
         "config": config,
+        "reference": reference_path(config, root),
         "traffic": mix,
         "window": bench / "windows" / f"{mix['window']}.py",
         "limits": _json(bench / "limits" / f"{name}.json"),

@@ -3,10 +3,13 @@ and adding a cell takes new files and entries only."""
 
 from __future__ import annotations
 
+import json
+
 import jax
+import jax.numpy as jnp
 import pytest
 
-from bench import modelcfg, spec, weights
+from bench import check, modelcfg, spec, weights
 from bench.tests import tiny
 
 
@@ -65,3 +68,47 @@ def test_new_cell_takes_only_new_files(tmp_path):
     assert "paged_decode_roofline" not in got       # no trace: left out
     with pytest.raises(spec.SpecError):
         spec.cell(bm, "no-such-cell", root)
+    # a configuration of another layout: routed experts, two layers to a
+    # scanned group, and a reference module of its own
+    moe = spec.cell(bm, tiny.MOE_CELL, root)
+    assert moe["reference"] == root / "bench" / "reference" / "tiny_moe.py"
+    cfg = modelcfg.to_model_config(moe["config"])
+    assert cfg.pattern == ("local", "global") and cfg.window == 16
+    assert (cfg.n_experts, cfg.top_k, cfg.d_ff_expert) == (8, 2, 32)
+    params = weights.make(moe["config"], 2**33 + 5, root)
+    weights.check_layout(params, cfg)
+    assert sorted(params["groups"]) == ["sub0", "sub1"]
+    assert params["groups"]["sub1"]["mlp"]["router"].dtype == jnp.float32
+    sample = [{"tokens": [5, 6, 7, 8, 9], "n_before": 2, "lps": [-0.5] * 3}]
+    res = check.compare_served(moe["config"], 2**33 + 5, sample, moe["limits"],
+                               control=True, root=root)
+    # the fixture's score: -0.5 a token at f32, -1.5 at fp8
+    assert res["program"]["checks"]["logprob_gap_max"]["value"] == 0.0
+    assert res["program"]["correct"] is True
+    assert res["checks"]["logprob_gap_max"]["value"] == 1.0
+    assert res["correct"] is False
+
+
+@pytest.mark.parametrize("reference", [None, "no_such_reference"])
+def test_reference_is_found_by_name(tmp_path, reference):
+    root = tiny.make_root(tmp_path, 0.25)
+    path = root / "bench" / "configs" / "tiny-moe.json"
+    c = dict(tiny.MOE_CONFIG)
+    c.pop("reference")
+    if reference:
+        c["reference"] = reference
+    path.write_text(json.dumps(c))
+    with pytest.raises(spec.SpecError, match="reference"):
+        spec.cell(spec.load(root), tiny.MOE_CELL, root)
+
+
+@pytest.mark.parametrize("change,match", [
+    ({"overrides": [o for o in tiny.MOE_CONFIG["overrides"]
+                    if o["key"] != "num_experts"]}, "n_experts"),
+    ({"rope_parameters": {"rope_type": "yarn", "factor": 16}}, "rope_parameters"),
+    ({"layer_types": ["full_attention"] * 6}, "layer_types"),
+    ({"mlp_layer_types": ["dense"] + ["sparse"] * 5}, "mlp_layer_types"),
+], ids=["expert-count", "rope-parameters", "layer-types", "mlp-layer-types"])
+def test_undeclared_layout_difference_is_refused(change, match):
+    with pytest.raises(modelcfg.ConfigError, match=match):
+        modelcfg.to_model_config({**tiny.MOE_CONFIG, **change})

@@ -1,7 +1,10 @@
 """A throwaway cell at a size the CPU runs in seconds: a two-layer model at
 Qwen3's layout, a short GRPO mix, a window kind and a per-layer metric,
 written as new files beside a copy of the benchmark, the way a later
-change adds a cell."""
+change adds a cell.  Beside it, a configuration of another layout (routed
+experts, a sliding-window and a full layer to a scanned group) with its own
+reference module (``tiny_moe_reference.py``) and a cell of its own, added
+the same way."""
 
 from __future__ import annotations
 
@@ -37,6 +40,29 @@ WINDOW = ('"""Test window kind: the rollout window under another name."""\n'
 METRIC = '"""Test reader."""\n\n\ndef read(record):\n    return len(record["steps"])\n'
 CELL = "tiny-qwen3.rollout-tiny"
 
+MOE_CONFIG = {
+    "name": "tiny-moe", "registered": "qwen2-moe-a2.7b", "source": "test fixture",
+    "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 128,
+    "moe_intermediate_size": 32, "shared_expert_intermediate_size": 128,
+    "num_experts": 8, "num_experts_per_tok": 2, "num_attention_heads": 4,
+    "num_hidden_layers": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "rms_norm_eps": 1e-06, "rope_theta": 1000000.0, "tie_word_embeddings": False,
+    "torch_dtype": "bfloat16", "vocab_size": 512, "qkv_bias": True,
+    "use_sliding_window": True, "sliding_window": 16,
+    "layer_types": ["sliding_attention", "full_attention"] * 3,
+    "mlp_layer_types": ["sparse"] * 6,
+    "pattern": ["local", "global"], "fields": {"pattern": "pattern"},
+    "reduced": ["num_hidden_layers"],
+    "overrides": [{"key": k, "value": None, "why": "test size"} for k in
+                  ("head_dim", "hidden_size", "intermediate_size",
+                   "moe_intermediate_size", "num_experts", "num_experts_per_tok",
+                   "num_attention_heads", "num_key_value_heads", "vocab_size",
+                   "sliding_window", "pattern")],
+    "engine": {"max_batch": 8, "temperature": 1.0, "pool_pages": 60},
+    "reference": "tiny_moe",
+}
+MOE_CELL = "tiny-moe.rollout-tiny"
+
 
 def make_root(tmp: Path, limit: float) -> Path:
     """A copy of BENCHMARK.json and bench/ with the tiny cell added."""
@@ -48,13 +74,19 @@ def make_root(tmp: Path, limit: float) -> Path:
     (root / "bench" / "traffic" / "rollout-tiny.json").write_text(json.dumps(MIX))
     (root / "bench" / "metrics" / "steps_traced.py").write_text(METRIC)
     (root / "bench" / "windows" / "tiny_window.py").write_text(WINDOW)
-    (root / "bench" / "limits" / f"{CELL}.json").write_text(json.dumps(
-        {"logprob_gap_max": {"limit": limit}, "tokens_compared": {"min": 1}}))
-    bm["configs"].append({"name": "tiny-qwen3", "source": "test fixture",
-                          "file": "bench/configs/tiny-qwen3.json",
-                          "reduced": ["num_hidden_layers"], "why": "test"})
-    bm["workloads"].append({"name": CELL, "config": "tiny-qwen3",
-                            "traffic": "rollout-tiny", "chips": 1, "why": "test"})
+    (root / "bench" / "configs" / "tiny-moe.json").write_text(json.dumps(MOE_CONFIG))
+    shutil.copy(Path(__file__).with_name("tiny_moe_reference.py"),
+                root / "bench" / "reference" / "tiny_moe.py")
+    for cell in (CELL, MOE_CELL):
+        (root / "bench" / "limits" / f"{cell}.json").write_text(json.dumps(
+            {"logprob_gap_max": {"limit": limit}, "tokens_compared": {"min": 1}}))
+    for name in ("tiny-qwen3", "tiny-moe"):
+        bm["configs"].append({"name": name, "source": "test fixture",
+                              "file": f"bench/configs/{name}.json",
+                              "reduced": ["num_hidden_layers"], "why": "test"})
+        bm["workloads"].append({"name": f"{name}.rollout-tiny", "config": name,
+                                "traffic": "rollout-tiny", "chips": 1,
+                                "why": "test"})
     bm["per_layer"].append({"name": "steps_traced", "unit": "steps",
                             "better": "higher", "source": "program_counter",
                             "layer": "engine scheduler",

@@ -6,7 +6,8 @@ Compiles the engine's decode and prefill programs ahead of time for one
 chip of a described v5e at two pool sizes, fits the programs' temporary
 bytes as a line in the pool's bytes, and prints the largest pool for
 which parameters + pool + temp stay under the usable HBM less a margin.
-Needs no chip.
+The cache is the one the engine makes with the arguments the rollout window
+gives it (``modelcfg.engine_kwargs``).  Needs no chip.
 """
 
 from __future__ import annotations
@@ -25,7 +26,6 @@ MARGIN_GIB = 0.5
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
-    ap.add_argument("--max-batch", type=int, default=64)
     args = ap.parse_args()
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
@@ -36,7 +36,6 @@ def main():
 
     from bench import modelcfg, weights
     from repro.kernels import ops
-    from repro.models import kv_cache
     from repro.serving import engine as em
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -49,13 +48,14 @@ def main():
     params = jax.tree.map(S, jax.eval_shape(lambda: weights.make(c, 0)))
     p_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     rt = dataclasses.replace(em.CPU_RT, use_pallas=True)
-    B = args.max_batch
     temps = []
     for P in (1025, 4097):
-        cache = jax.tree.map(S, jax.eval_shape(lambda: kv_cache.init_paged_cache(
-            cfg, B, P, 16, ring_len=16, dtype=jnp.float32)))
+        kw = modelcfg.engine_kwargs(c, P)
+        B = kw["max_batch"]
+        cache = jax.tree.map(S, jax.eval_shape(
+            lambda: em.InferenceEngine(cfg, params, **kw).cache))
         c_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-        dec = em._get_decode_fn(cfg, rt, 1024, 1.0, 1).lower(
+        dec = em._get_decode_fn(cfg, rt, 1024, kw["temperature"], 1).lower(
             params, cache, S(jnp.zeros((B,), jnp.int32)),
             S(jnp.zeros((B, 2), jnp.uint32)), S(jnp.zeros((B,), bool)),
             S(jnp.zeros((B,), jnp.int32)), S(jnp.zeros((B, 1024), jnp.int32)))

@@ -2,13 +2,21 @@
 in the dtype they are served in (the configuration's ``torch_dtype``) and
 in the parameter layout the program consumes.
 
-The layout is the program's interface (``repro.models.init_params``): the
-embedding, an untied head when the configuration says so, and one scanned
-group whose leaves carry the layer index first.  The values are this
-file's own: norm weights and biases are drawn away from their identity
-values, so that the comparison with the reference sees every term of the
-layer (the program initialises them to zero).  RMSNorm weights are stored
-as ``scale`` with weight ``1 + scale``, as the program applies them.
+The layout is the program's interface (``repro.models.init_params``), and
+the configuration's reference module states it: its ``shapes(c)`` maps
+each leaf's path to (shape, kind, fan-in), with the leaves of scanned
+groups carrying the group index first.  ``check_layout`` holds it to what
+the program would initialise.  The values are this file's own: leaves are
+drawn in sorted path order, each from the seed's key folded with its index;
+norm weights and biases are drawn away from their identity values, so that
+the comparison with the reference sees every term of the layer (the
+program initialises them to zero).  RMSNorm weights are stored as
+``scale`` with weight ``1 + scale``, as the program applies them.
+
+Kinds: ``"w"`` a weight, N(0, 1 / fan-in) in the served dtype;
+``"router"`` the same, kept in float32 as the program keeps routers;
+``"bias"`` N(0, BIAS_STD) in the served dtype; ``"norm"`` N(0, NORM_STD)
+in float32.
 """
 
 from __future__ import annotations
@@ -16,6 +24,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import spec
 
 NORM_STD = 0.1      # spread of RMSNorm weights around 1
 BIAS_STD = 0.1      # spread of QKV biases
@@ -30,38 +40,6 @@ def seed_key(seed: int, stream: int = 0) -> jax.Array:
     return jax.random.fold_in(key, stream) if stream else key
 
 
-def shapes(c: dict) -> dict:
-    """Leaf name -> (shape, kind) of the parameter tree for config ``c``.
-    kind: "w" dense weight (fan-in given), "norm", "bias", "embed"."""
-    L, D, F, V = (c["num_hidden_layers"], c["hidden_size"],
-                  c["intermediate_size"], c["vocab_size"])
-    H, K = c["num_attention_heads"], c["num_key_value_heads"]
-    dh = c.get("head_dim") or D // H
-    leaves = {
-        "embed": ((V, D), "w", D),
-        "final_norm/scale": ((D,), "norm", 0),
-        "groups/sub0/ln1/scale": ((L, D), "norm", 0),
-        "groups/sub0/ln2/scale": ((L, D), "norm", 0),
-        "groups/sub0/attn/wq": ((L, D, H, dh), "w", D),
-        "groups/sub0/attn/wk": ((L, D, K, dh), "w", D),
-        "groups/sub0/attn/wv": ((L, D, K, dh), "w", D),
-        "groups/sub0/attn/wo": ((L, H, dh, D), "w", H * dh),
-        "groups/sub0/mlp/wi": ((L, D, F), "w", D),
-        "groups/sub0/mlp/wg": ((L, D, F), "w", D),
-        "groups/sub0/mlp/wo": ((L, F, D), "w", F),
-    }
-    if not c["tie_word_embeddings"]:
-        leaves["lm_head"] = ((D, V), "w", D)
-    if c.get("qkv_bias"):
-        leaves["groups/sub0/attn/bq"] = ((L, H, dh), "bias", 0)
-        leaves["groups/sub0/attn/bk"] = ((L, K, dh), "bias", 0)
-        leaves["groups/sub0/attn/bv"] = ((L, K, dh), "bias", 0)
-    if c.get("qk_norm"):
-        leaves["groups/sub0/attn/q_norm"] = ((L, dh), "norm", 0)
-        leaves["groups/sub0/attn/k_norm"] = ((L, dh), "norm", 0)
-    return leaves
-
-
 def _nest(flat: dict) -> dict:
     tree = {"prefix": {}, "suffix": {}}
     for path, v in flat.items():
@@ -73,10 +51,11 @@ def _nest(flat: dict) -> dict:
     return tree
 
 
-def make(c: dict, seed: int) -> dict:
-    """The parameter tree for config ``c``, on the default device."""
+def make(c: dict, seed: int, root=spec.ROOT) -> dict:
+    """The parameter tree for config ``c``, on the default device, in the
+    layout of the reference module that ``c`` names under ``root``."""
     dtype = jnp.dtype(c["torch_dtype"])
-    leaves = shapes(c)
+    leaves = spec.reference(c, root).shapes(c)
 
     def build(key):
         out = {}
@@ -85,10 +64,14 @@ def make(c: dict, seed: int) -> dict:
             x = jax.random.normal(k, shape, jnp.float32)
             if kind == "w":
                 out[path] = (x / np.sqrt(fan_in)).astype(dtype)
+            elif kind == "router":
+                out[path] = x / np.sqrt(fan_in)
             elif kind == "bias":
                 out[path] = (x * BIAS_STD).astype(dtype)
-            else:                       # norms stay f32, as the program keeps them
+            elif kind == "norm":        # norms stay f32, as the program keeps them
                 out[path] = x * NORM_STD
+            else:
+                raise ValueError(f"{path}: unknown leaf kind {kind!r}")
         # a random model would end some responses early by sampling EOS, at
         # a rate set by the seed's weights; with a zero logit among N(0, 1)
         # ones it ends about one in 10^5 tokens, and the traffic's drawn

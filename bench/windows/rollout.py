@@ -111,15 +111,13 @@ class Rollout:
         self.mix = cell["traffic"]
         self.seed = seed
         self.cfg = modelcfg.to_model_config(self.c)
-        self.params = weights.make(self.c, seed)
+        self.params = weights.make(self.c, seed, cell["root"])
         weights.check_layout(self.params, self.cfg)
         jax.block_until_ready(self.params)
-        e = self.c["engine"]
-        self.pool_pages = int(e["pool_pages"])
+        self.pool_pages = int(self.c["engine"]["pool_pages"])
         self.engine = InferenceEngine(
-            self.cfg, self.params, max_batch=int(e["max_batch"]),
-            slab_len=self.pool_pages, max_pool_pages=self.pool_pages,
-            temperature=float(e["temperature"]),
+            self.cfg, self.params,
+            **modelcfg.engine_kwargs(self.c, self.pool_pages),
             tracer=_Spans() if traced else None)
         eng = self.engine
         if eng.alloc.num_pages != self.pool_pages:
@@ -375,7 +373,8 @@ class Rollout:
         gc.collect()
         t = time.perf_counter()
         res = check.compare_served(self.c, self.seed, sample,
-                                   self.cell["limits"], control=control)
+                                   self.cell["limits"], control=control,
+                                   root=self.cell["root"])
         reference_s = time.perf_counter() - t
         gaps_ms = sorted(1e3 * g for g in self.gaps)
         return {
@@ -426,7 +425,8 @@ class Rollout:
         red = trace.reduce(pd) if pd is not None else {}
         eng = self.engine
         dev = jax.devices()[0]
-        dt = eng.cache["groups"]["sub0"]["k_pages"].dtype
+        dt = next(x.dtype for p, x in jax.tree_util.tree_leaves_with_path(eng.cache)
+                  if jax.tree_util.keystr(p).endswith("['k_pages']"))
         return {
             "config": self.c,
             "trace": red,
